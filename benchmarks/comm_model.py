@@ -138,6 +138,9 @@ def run_strategies(scale: int = 10, th: int = 64, p_rank: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--strategies", action="store_true",
                     help="sweep comm strategies on msBFS, emit BENCH_comm.json")

@@ -67,4 +67,7 @@ def run(scale: int = 14, ths=(16, 64, 256), p_rank: int = 2, p_gpu: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_scaling.json")

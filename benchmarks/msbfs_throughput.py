@@ -591,6 +591,9 @@ def run_mixed(scale: int = 10, edge_factor: int = 8, th: int = 64,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -52,4 +52,7 @@ def run(scale: int = 12, th: int = 64, p_rank: int = 2, p_gpu: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_comm.json")

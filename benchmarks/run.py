@@ -12,6 +12,9 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (comm_model, memory_model, msbfs_throughput,
                             options_ablation, strong_scaling, th_perf,
                             th_sweep, weak_scaling)

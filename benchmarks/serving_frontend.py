@@ -245,6 +245,9 @@ def run_frontend(scale: int = 7, th: int = 64, p_rank: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -43,4 +43,7 @@ def run(scale: int = 12, ps=(1, 2, 4, 8), th: int = 64,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_scaling.json")

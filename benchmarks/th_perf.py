@@ -41,4 +41,7 @@ def run(scale: int = 12, ths=(8, 32, 64, 128, 512), p_rank: int = 2, p_gpu: int 
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_comm.json")

@@ -49,4 +49,7 @@ def run(scale: int = 16, ths=(4, 8, 16, 32, 64, 128, 256, 512, 1024),
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_comm.json")

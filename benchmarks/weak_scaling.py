@@ -52,4 +52,7 @@ def run(scale_per_part: int = 9, ps=(1, 2, 4, 8), th: int = 32,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(out_json="BENCH_scaling.json")

@@ -41,7 +41,8 @@ bracketing (``repro.obs.DispatchProfiler``), printed at the end and
 written as a ``CALIB_device.json``-style artifact (``--calib-out``,
 ``repro-bench/1`` schema -- what ``scripts/bench_gate.py`` diffs and
 ``repro.launch.roofline --calib`` renders). ``--profile-trace-dir``
-additionally captures a ``jax.profiler`` device trace (best-effort).
+additionally captures a ``jax.profiler`` device trace (a failed capture
+fails the run).
 
     PYTHONPATH=src python examples/bfs_serving.py [--scale 11] [--requests 400] \
         [--refill] [--overlap] [--stream] [--mixed] [--delegate ring] \
@@ -208,15 +209,17 @@ def main():
                     help="calibration artifact path for --profile")
     ap.add_argument("--profile-trace-dir", default=None,
                     help="also capture a jax.profiler device trace into "
-                         "this directory (best-effort)")
+                         "this directory")
     ap.add_argument("--sample-rate", type=float, default=1.0,
                     help="dispatch-latency sample rate for --profile")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.core import msbfs as M
     from repro.core.comm import CommConfig
     from repro.obs import DispatchProfiler, Observability, skew
 
+    enable_compile_cache()
     if args.overlap or args.stream:
         args.refill = True   # the pipelined drivers ride the refill path
     obs = Observability() if args.trace else None

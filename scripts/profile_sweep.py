@@ -152,11 +152,14 @@ def main(argv=None) -> int:
                     help="dispatch-latency sample rate (0 < r <= 1)")
     ap.add_argument("--trace-dir", default=None,
                     help="capture a jax.profiler trace per cell into this "
-                         "directory (best-effort)")
+                         "directory (a failed capture fails the run)")
     ap.add_argument("--out", default="CALIB_device.json",
                     help="calibration artifact path (repro-bench/1 schema)")
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run_matrix(scale=args.scale, edge_factor=args.edge_factor,
                requests=args.requests, n_queries=args.n_queries,
                delegates=tuple(args.delegates),

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
-
 from . import comm
 from .types import CSR, INF_LEVEL, PartitionedGraph, PartitionLayout
 
@@ -559,7 +557,7 @@ def make_sharded_bfs(mesh, partition_axes: Sequence[str], cfg: BFSConfig,
                                plan=squeeze(pl_l))
                 return unsq(new)
 
-            return compat.shard_map(
+            return jax.shard_map(
                 local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False)(pgv, plan, st)
 
@@ -579,7 +577,7 @@ def make_sharded_bfs(mesh, partition_axes: Sequence[str], cfg: BFSConfig,
             new = bfs_step(squeeze(pg_l), squeeze(st_l), cfg, axes)
             return unsq(new)
 
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )(pgv, st)
 

@@ -34,7 +34,7 @@ import math
 from dataclasses import dataclass
 from typing import Sequence
 
-from repro import compat
+from jax import lax
 
 AxisNames = Sequence[str] | str
 
@@ -82,7 +82,9 @@ def as_axes(axis_names: AxisNames) -> tuple:
 
 
 def axis_size(axis_names: AxisNames) -> int:
-    return compat.axis_size(axis_names)
+    """Size of one named axis, or the product over a sequence of them
+    (a static int under both ``vmap(axis_name=...)`` and ``shard_map``)."""
+    return math.prod(lax.axis_size(a) for a in as_axes(axis_names))
 
 
 @dataclass(frozen=True)
@@ -255,8 +257,8 @@ class CommPlan:
 def plan_for(cfg: CommConfig | None, axis_names: AxisNames) -> CommPlan:
     """Bind ``cfg`` to the traced step's partition axes. Axis sizes resolve
     to static Python ints under both ``vmap(axis_name=...)`` and
-    ``shard_map`` (``compat.axis_size``), so the plan -- and every byte
+    ``shard_map`` (``lax.axis_size``), so the plan -- and every byte
     formula on it -- is compile-time data."""
     axes = as_axes(axis_names)
-    sizes = tuple(compat.axis_size(a) for a in axes)
+    sizes = tuple(lax.axis_size(a) for a in axes)
     return CommPlan(cfg=cfg or CommConfig(), axes=axes, sizes=sizes)

@@ -68,8 +68,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
-
 from . import comm
 from .bfs import _decide_direction, _row_degrees
 from .types import CSR, INF_LEVEL, PartitionedGraph, PartitionLayout
@@ -1214,10 +1212,9 @@ def _reseed_lanes_impl(
 
 
 # The public jitted entry point, plus an input-donating sibling for the
-# overlapped serving pipeline: at a retirement boundary the pre-reseed state
-# has already been gathered from, so its buffers can be reused in place.
-# (XLA:CPU ignores donation; the serve engine only picks the donating
-# variant on accelerator backends to avoid per-call warnings.)
+# serving drivers: a state that nothing else references (the engine tracks
+# this as ``exclusive``) is reseeded in place. A donated input is deleted
+# on every backend, XLA:CPU included.
 reseed_lanes = jax.jit(_reseed_lanes_impl)
 reseed_lanes_donated = jax.jit(_reseed_lanes_impl, donate_argnums=(0,))
 
@@ -1282,7 +1279,7 @@ def _make_sharded_step(mesh, axes: tuple, cfg: MSBFSConfig):
             return unsq(msbfs_step(squeeze(pg_l), squeeze(pl_l), squeeze(st_l),
                                    cfg, axes))
 
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False)(pgv, plan, st)
 
@@ -1347,8 +1344,8 @@ def make_msbfs_block_emulated(cfg: MSBFSConfig, k: int, donate: bool = False):
     ``block(pgv_stacked, plan_stacked, state, watch) -> state`` runs up to
     ``k`` supersteps on device per host round trip (see :func:`_block_loop`
     for the exact-stop semantics). ``donate=True`` donates the input
-    state's buffers to the output (in-place sweeps on backends that
-    support it; XLA:CPU silently ignores donation)."""
+    state's buffers to the output (in-place sweeps); the input state is
+    deleted on every backend, so only pass a state nothing else holds."""
     step = _vmapped_step(cfg)
 
     def block(pgv_stacked, plan_stacked, state, watch):

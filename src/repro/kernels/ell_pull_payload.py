@@ -8,10 +8,10 @@ with zero weights, min-label propagation for components):
 
     out[r, q] = min_{k: parents[r,k] >= 0} (payload[parents[r,k], q] + w[r,k])
 
-masked to the identity (+inf) where ``active[r, q] == 0``. Same tiling as
-ell_pull_multi: one program per tile of TR rows, the payload table
-resident in VMEM, the min across the static row width an unrolled
-min-chain on the VPU.
+masked to the identity (+inf) where ``active[r, q] == 0``. Same layout as
+ell_pull_multi: XLA gathers the parents' payload rows from HBM into a
+rows-minor ``[W, K, R]`` plane, and the kernel adds the edge weights,
+masks padded parents and min-folds the K columns per tile of rows.
 """
 from __future__ import annotations
 
@@ -23,23 +23,21 @@ from jax.experimental import pallas as pl
 
 from repro.core.comm import COMBINE_SPECS
 
-DEFAULT_TILE_ROWS = 256
+from .ell_pull_multi import LANES, rows_major, rows_minor
+
+DEFAULT_TILE_ROWS = 1024
 _IDENT = COMBINE_SPECS["min_plus"].identity
 
 
 def _kernel(parents_ref, payload_ref, weights_ref, active_ref, out_ref):
-    cols = parents_ref[...]                     # [TR, K] int32, -1 padded
-    table = payload_ref[...]                    # [N, W] int32 payloads
-    wts = weights_ref[...]                      # [TR, K] int32 edge weights
-    active = active_ref[...]                    # [TR, W] int32 lane mask
-    valid = cols >= 0
-    safe = jnp.where(valid, cols, 0)
-    vals = jnp.take(table, safe, axis=0) + wts[..., None]   # [TR, K, W]
-    vals = jnp.where(valid[..., None], vals, jnp.int32(_IDENT))
-    acc = jnp.full(active.shape, _IDENT, jnp.int32)
-    for k in range(vals.shape[1]):              # unrolled min-plus chain
-        acc = jnp.minimum(acc, vals[:, k])
-    out_ref[...] = jnp.where(active != 0, acc, jnp.int32(_IDENT))
+    # parents/weights [K, TS, 128] int32; payload [W, K, TS, 128] int32
+    # gathered parent rows; active/out [W, TS, 128] int32
+    acc = jnp.full(out_ref.shape, _IDENT, jnp.int32)
+    for k in range(parents_ref.shape[0]):       # unrolled min-plus chain
+        valid = parents_ref[k] >= 0
+        vals = payload_ref[:, k] + weights_ref[k][None]
+        acc = jnp.minimum(acc, jnp.where(valid[None], vals, jnp.int32(_IDENT)))
+    out_ref[...] = jnp.where(active_ref[...] != 0, acc, jnp.int32(_IDENT))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
@@ -56,22 +54,27 @@ def ell_pull_payload(
     w = payload.shape[-1]
     if k == 0:  # no parent columns: pallas rejects zero-width blocks
         return jnp.full((r, w), _IDENT, jnp.int32)
+    if tile_rows % LANES:
+        raise ValueError(f"tile_rows={tile_rows} is not a multiple of {LANES}")
     r_pad = -(-r // tile_rows) * tile_rows
-    parents = jnp.pad(parents, ((0, r_pad - r), (0, 0)), constant_values=-1)
-    weights = jnp.pad(weights, ((0, r_pad - r), (0, 0)))
-    active = jnp.pad(active, ((0, r_pad - r), (0, 0)))
-    grid = (r_pad // tile_rows,)
+    ts = tile_rows // LANES
+    cols = rows_minor(parents, r_pad, fill=-1)                # [K, S, 128]
+    wts = rows_minor(weights, r_pad)                          # [K, S, 128]
+    # XLA gather from HBM along the transposed table: [W, K, S, 128],
+    # rows minor throughout (no lane padding of W)
+    vals = payload.T[:, jnp.maximum(cols, 0)]
+    act = rows_minor(active, r_pad)                           # [W, S, 128]
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(r_pad // tile_rows,),
         in_specs=[
-            pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec(payload.shape, lambda i: (0, 0)),
-            pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, w), lambda i: (i, 0)),
+            pl.BlockSpec((k, ts, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((w, k, ts, LANES), lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((k, ts, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((w, ts, LANES), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_rows, w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r_pad, w), jnp.int32),
+        out_specs=pl.BlockSpec((w, ts, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(act.shape, jnp.int32),
         interpret=interpret,
-    )(parents, payload, weights, active)
-    return out[:r]
+    )(cols, vals, wts, act)
+    return rows_major(out, r)

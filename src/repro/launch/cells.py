@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_arch
 from repro.core import bfs as BFS
 from repro.models import equivariant as EQ, gnn as G, lm as LM, recsys as R
@@ -146,8 +145,8 @@ def _sharded_dist_step(mesh, axes, local_step, n_stacked: int):
             jax.tree.map(lambda _: P(), opt_state),
             P(axes),
         )
-        return compat.shard_map(local, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)(
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
             params, opt_state, *stacked)
 
     return wrapped

@@ -5,6 +5,13 @@ from __future__ import annotations
 import math
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def _mesh(devices, shape, axes) -> Mesh:
+    return Mesh(np.asarray(devices).reshape(shape), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,19 +26,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {ndev} devices for the production mesh, have {len(devices)}; "
             "the dry-run driver must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    import numpy as np
-
-    from repro.compat import make_mesh
-    return make_mesh(np.asarray(devices).reshape(shape), axes)
+    return _mesh(devices, shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many host devices tests forced."""
-    import numpy as np
-
-    from repro.compat import make_mesh
-    ndev = math.prod(shape)
-    return make_mesh(np.asarray(jax.devices()[:ndev]).reshape(shape), axes)
+    return _mesh(jax.devices()[:math.prod(shape)], shape, axes)
 
 
 def data_axes(mesh) -> tuple:

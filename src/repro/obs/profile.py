@@ -24,8 +24,8 @@ obs plane) and are mirrored into an attached
 Optional ``jax.profiler`` session capture: construct with
 ``trace_dir=...`` and wrap the serving window in :meth:`trace_session`
 (or call :meth:`start_trace` / :meth:`stop_trace`) to drop a TensorBoard/
-Perfetto device trace next to the sampled latencies. Capture failures are
-swallowed -- profiling must never take serving down.
+Perfetto device trace next to the sampled latencies. A capture that was
+asked for and fails raises: a run that exits cleanly has its trace.
 
 Surfaced as ``BFSServeEngine(profile=...)`` / ``ServeFrontend(profile=
 ...)``: pass a profiler instance, ``True`` (sample every dispatch), or a
@@ -111,29 +111,26 @@ class DispatchProfiler:
     def start_trace(self) -> bool:
         """Begin a ``jax.profiler`` capture into ``trace_dir`` (no-op
         without one, or when already tracing). Returns True iff a capture
-        actually started; failures are swallowed."""
+        started; a capture that fails to start raises."""
         if self.trace_dir is None or self._tracing:
             return False
-        try:
-            jax.profiler.start_trace(self.trace_dir)
-        except Exception:  # noqa: BLE001 -- capture is best-effort
-            return False
+        jax.profiler.start_trace(self.trace_dir)
         self._tracing = True
         return True
 
     def stop_trace(self) -> None:
+        """End the capture :meth:`start_trace` began and write it out (a
+        failure to write raises)."""
         if not self._tracing:
             return
         self._tracing = False
-        try:
-            jax.profiler.stop_trace()
-        except Exception:  # noqa: BLE001
-            pass
+        jax.profiler.stop_trace()
 
     @contextlib.contextmanager
     def trace_session(self):
-        """``with profiler.trace_session(): serve(...)`` -- best-effort
-        ``jax.profiler`` capture around the block."""
+        """``with profiler.trace_session(): serve(...)`` -- a
+        ``jax.profiler`` capture around the block when ``trace_dir`` is
+        set."""
         self.start_trace()
         try:
             yield self

@@ -410,9 +410,6 @@ class BFSServeEngine:
         if int(sweep_block) < 1:
             raise ValueError(f"sweep_block must be >= 1, got {sweep_block}")
         self.sweep_block = int(sweep_block)
-        # XLA:CPU ignores buffer donation (and warns); only donate where it
-        # actually buys in-place sweeps
-        self._donate = jax.default_backend() != "cpu"
         self._stream: _Session | None = None
         self.specialize_reachability = bool(specialize_reachability)
         self.reuse_components = bool(reuse_components)
@@ -549,7 +546,7 @@ class BFSServeEngine:
 
     def _block_pair(self, cfg: M.MSBFSConfig) -> tuple:
         """(block, block_donated) fused k-sweep runners for ``cfg``."""
-        key = ("block", self._shape_key, cfg, self.sweep_block, self._donate)
+        key = ("block", self._shape_key, cfg, self.sweep_block)
         pair = self._runners.get(key)
         if pair is None:
             k = self.sweep_block
@@ -559,9 +556,7 @@ class BFSServeEngine:
             else:
                 mk = lambda don: M.make_msbfs_block_emulated(
                     cfg, k, donate=don)
-            blk = mk(False)
-            pair = self._runners[key] = (blk, mk(True) if self._donate
-                                         else blk)
+            pair = self._runners[key] = (mk(False), mk(True))
         return pair
 
     def _reach_fast(self, queries) -> bool:
@@ -891,7 +886,7 @@ class BFSServeEngine:
 
     def _reseed(self, sess: _Session, assignments):
         desc = self._seed_descriptors(assignments, payload=sess.cfg.payload)
-        reseed = (M.reseed_lanes_donated if self._donate and sess.exclusive
+        reseed = (M.reseed_lanes_donated if sess.exclusive
                   else M.reseed_lanes)
         return reseed(sess.state, *map(jnp.asarray, desc))
 
@@ -1075,8 +1070,7 @@ class BFSServeEngine:
                     return False
                 self._fill(sess, initial=sess.sweeps == 0)
             watch = np.ascontiguousarray(sched.busy)
-            blockfn = (sess.block_donated if self._donate and sess.exclusive
-                       else sess.block)
+            blockfn = sess.block_donated if sess.exclusive else sess.block
             if obs.enabled:
                 obs.trace.instant("serve.block.dispatch", busy=sched.n_busy)
             sess.cur = self.profiler.timed(
@@ -1144,8 +1138,8 @@ class BFSServeEngine:
             sess.head = None
             if sched.n_busy:
                 watch = np.ascontiguousarray(sched.busy)
-                blockfn = (sess.block_donated
-                           if self._donate and sess.exclusive else sess.block)
+                blockfn = (sess.block_donated if sess.exclusive
+                           else sess.block)
                 if obs.enabled:
                     obs.trace.instant("serve.block.dispatch",
                                       busy=sched.n_busy)
@@ -1474,42 +1468,54 @@ class BFSServeEngine:
         return sampler.sample(pool)
 
     def warmup(self, reachability: bool = False, targets: bool = False,
-               payload: bool = False) -> None:
-        """Compile the runners for the configured scheduling mode (vertex 0
-        as a throwaway source). Refill engines only drive the single-step
-        runner, so the fused while-loop compile is skipped there (it still
-        compiles lazily if ``run_batch`` is called directly).
+               payload: bool = False, queries=None) -> None:
+        """Compile the runners the configured driver dispatches (vertex 0
+        as a throwaway source): the fused while-loop for batch engines;
+        the single-step runner for the per-sweep refill driver; the fused
+        k-sweep block for the overlapped one. Refill engines also compile
+        the lane reseed, and both donating siblings the drivers use on a
+        state nothing else holds.
 
         By default only the target-free levels variant (the common serving
         case) is compiled; ``targets=True`` adds the multi-target variant,
         ``reachability=True`` the levels-free reachability one, and
         ``payload=True`` the payload-plane (WEIGHTED_SSSP / COMPONENTS)
-        one."""
-        cfgs = [_dc_replace(self.cfg, enable_targets=False)]
-        if targets:
-            cfgs.append(self.cfg)
-        if reachability and self.specialize_reachability:
-            cfgs.append(_dc_replace(self.cfg, track_levels=False,
-                                    enable_targets=False))
-        if payload:
-            cfgs.append(self._payload_cfg(
-                _dc_replace(self.cfg, enable_targets=False)))
-            if targets:        # mixed sessions carrying both planes
-                cfgs.append(self._payload_cfg(self.cfg))
+        one. ``queries`` instead compiles exactly the one variant that a
+        batch or drain of those queries runs."""
+        if queries is not None:
+            cfgs = [self._session_cfg([as_query(q) for q in queries])]
+        else:
+            cfgs = [_dc_replace(self.cfg, enable_targets=False)]
+            if targets:
+                cfgs.append(self.cfg)
+            if reachability and self.specialize_reachability:
+                cfgs.append(_dc_replace(self.cfg, track_levels=False,
+                                        enable_targets=False))
+            if payload:
+                cfgs.append(self._payload_cfg(
+                    _dc_replace(self.cfg, enable_targets=False)))
+                if targets:        # mixed sessions carrying both planes
+                    cfgs.append(self._payload_cfg(self.cfg))
         with self.obs.trace.span("serve.warmup", variants=len(cfgs)):
             for cfg in cfgs:
                 run_full, step_once = self._runner_pair(cfg)
-                st = self._put(M.init_multi_state(self.pg, [0], cfg))
-                if self.refill:
-                    step_once(self.pgv, self.plan, st)
-                    desc = self._seed_descriptors([], payload=cfg.payload)
-                    M.reseed_lanes(st, *map(jnp.asarray, desc))
-                    if self.overlap:
-                        # all-ones watch with only lane 0 active: the
-                        # block's stop condition fires at entry, so this
-                        # compiles the fused loop without running sweeps
-                        block, _ = self._block_pair(cfg)
-                        block(self.pgv, self.plan, st,
-                              np.ones(self.cfg.n_queries, dtype=bool))
-                else:
+                fresh = lambda: self._put(
+                    M.init_multi_state(self.pg, [0], cfg))
+                st = fresh()
+                if not self.refill:
                     run_full(self.pgv, self.plan, st)
+                    continue
+                desc = [jnp.asarray(x) for x in
+                        self._seed_descriptors([], payload=cfg.payload)]
+                M.reseed_lanes(st, *desc)
+                M.reseed_lanes_donated(fresh(), *desc)
+                if self.overlap:
+                    # all-ones watch with only lane 0 active: the block's
+                    # stop condition fires at entry, so this compiles the
+                    # fused loop without running sweeps
+                    watch = np.ones(self.cfg.n_queries, dtype=bool)
+                    block, block_donated = self._block_pair(cfg)
+                    block(self.pgv, self.plan, st, watch)
+                    block_donated(self.pgv, self.plan, fresh(), watch)
+                else:
+                    step_once(self.pgv, self.plan, st)

@@ -1,10 +1,10 @@
 """Hypothesis if installed, else a deterministic integers-only stand-in.
 
 The property tests only ever use ``st.integers`` with ``@given`` /
-``@settings(max_examples=..., deadline=None)``.  When hypothesis is absent
-(the pinned container does not ship it) the fallback replays the same
-decorator API with a fixed-seed RNG, so the tier-1 suite keeps exercising
-the properties instead of skipping the modules wholesale.
+``@settings(max_examples=..., deadline=None)``.  Where hypothesis is
+absent the fallback replays the same decorator API with a fixed-seed RNG,
+so the tier-1 suite keeps exercising the properties instead of skipping
+the modules wholesale.
 """
 try:
     from hypothesis import given, settings, strategies as st  # noqa: F401

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import bfs as B, comm, engine as E, msbfs as M
 from repro.core.oracle import bfs_levels
 from repro.core.partition import partition_graph
@@ -339,8 +338,8 @@ def _shard_reduce(mesh, axes, fn, x):
     from jax.sharding import PartitionSpec as P
 
     spec = P(axes, *([None] * (x.ndim - 1)))
-    f = compat.shard_map(lambda xl: fn(xl[0])[None], mesh=mesh,
-                         in_specs=spec, out_specs=spec, check_vma=False)
+    f = jax.shard_map(lambda xl: fn(xl[0])[None], mesh=mesh,
+                      in_specs=spec, out_specs=spec, check_vma=False)
     return jax.jit(f)(x)
 
 

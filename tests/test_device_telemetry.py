@@ -300,6 +300,25 @@ def test_trace_session_without_dir_is_noop():
     assert prof._tracing is False
 
 
+@pytest.mark.parametrize("stage", ["start", "stop"])
+def test_trace_capture_failure_propagates(tmp_path, monkeypatch, stage):
+    """A capture that was asked for (``trace_dir`` set) and fails raises out
+    of the traced window instead of leaving a clean run with no trace."""
+    def boom(*a, **k):
+        raise RuntimeError(f"profiler {stage} failed")
+
+    if stage == "start":
+        monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    else:
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", boom)
+    prof = DispatchProfiler(sample_rate=1.0, trace_dir=str(tmp_path))
+    with pytest.raises(RuntimeError, match=f"profiler {stage} failed"):
+        with prof.trace_session():
+            pass
+    assert prof._tracing is False
+
+
 # ------------------------------------------------- calibration artifact
 def _load_profile_sweep():
     path = os.path.join(_REPO, "scripts", "profile_sweep.py")

@@ -14,7 +14,6 @@ import sys
 sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
 from repro.core import bfs as B, engine as E
 from repro.core.partition import partition_graph
 from repro.core.oracle import bfs_levels
@@ -60,8 +59,8 @@ def local(prm, pgl, pl, wl, bt):
 in_specs = (jax.tree.map(lambda _: P(), params),
             *[jax.tree.map(lambda x: P(axes, *([None]*(x.ndim-1))), t)
               for t in (pgv2, plan, w, batch)])
-gfn = jax.jit(compat.shard_map(local, mesh=mesh, in_specs=in_specs,
-                               out_specs=jax.tree.map(lambda _: P(), params), check_vma=False))
+gfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=jax.tree.map(lambda _: P(), params), check_vma=False))
 gdist = gfn(params, *jax.tree.map(sh, (pgv2, plan, w, batch)))
 gb = GraphBatch(nodes=jnp.asarray(feats), senders=jnp.asarray(g2.src, jnp.int32),
                 receivers=jnp.asarray(g2.dst, jnp.int32))
