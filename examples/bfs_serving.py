@@ -36,21 +36,18 @@ changes the traversal schedule: the same sweeps, the same wire bytes.
 
 ``--profile`` turns on the device plane: in-jit sweep telemetry
 (``MSBFSConfig(telemetry=True)`` -- per-shard frontier totals and skew,
-harvested with zero extra host syncs) plus sampled dispatch-latency
-bracketing (``repro.obs.DispatchProfiler``), printed at the end and
-written as a ``CALIB_device.json``-style artifact (``--calib-out``,
-``repro-bench/1`` schema -- what ``scripts/bench_gate.py`` diffs and
-``repro.launch.roofline --calib`` renders). ``--profile-trace-dir``
-additionally captures a ``jax.profiler`` device trace (a failed capture
-fails the run).
+harvested with zero extra host syncs), printed at the end.
+``--profile-trace-dir`` additionally captures the serving window with
+``jax.profiler.trace``: device time under the traversal step's
+``msbfs.*`` scopes and, with ``--trace``, the engine's ``serve.*`` spans
+on the same clock.
 
     PYTHONPATH=src python examples/bfs_serving.py [--scale 11] [--requests 400] \
         [--refill] [--overlap] [--stream] [--mixed] [--delegate ring] \
         [--adaptive-nn] [--trace] [--profile]
 """
 import argparse
-import os
-import sys
+import contextlib
 import time
 
 import numpy as np
@@ -203,30 +200,23 @@ def main():
     ap.add_argument("--metrics-out", default="serve_metrics.json",
                     help="metrics snapshot JSON path")
     ap.add_argument("--profile", action="store_true",
-                    help="device plane: in-jit sweep telemetry + sampled "
-                         "dispatch-latency profiling; writes --calib-out")
-    ap.add_argument("--calib-out", default="CALIB_device.json",
-                    help="calibration artifact path for --profile")
+                    help="device plane: in-jit sweep telemetry")
     ap.add_argument("--profile-trace-dir", default=None,
-                    help="also capture a jax.profiler device trace into "
-                         "this directory")
-    ap.add_argument("--sample-rate", type=float, default=1.0,
-                    help="dispatch-latency sample rate for --profile")
+                    help="capture a jax.profiler trace of the serving "
+                         "window into this directory")
     args = ap.parse_args()
+
+    import jax
 
     from repro.compile_cache import enable_compile_cache
     from repro.core import msbfs as M
     from repro.core.comm import CommConfig
-    from repro.obs import DispatchProfiler, Observability, skew
+    from repro.obs import Observability, skew
 
     enable_compile_cache()
     if args.overlap or args.stream:
         args.refill = True   # the pipelined drivers ride the refill path
     obs = Observability() if args.trace else None
-    profiler = None
-    if args.profile:
-        profiler = DispatchProfiler(sample_rate=args.sample_rate,
-                                    trace_dir=args.profile_trace_dir)
     g = rmat_graph(args.scale, seed=0)
     print(f"graph n={g.n:,} m={g.m:,}")
     eng = BFSServeEngine(g, th=args.th, p_rank=2, p_gpu=2, cache_capacity=512,
@@ -236,8 +226,7 @@ def main():
                              delegate=args.delegate,
                              nn="compressed" if args.compressed_nn
                              else "adaptive" if args.adaptive_nn else "dense"),
-                         obs=obs, profile=profiler,
-                         edge_chunk=args.edge_chunk)
+                         obs=obs, edge_chunk=args.edge_chunk)
     t0 = time.perf_counter()
     # a mixed stream is never homogeneously-reachability, so only the
     # multi-target and payload-plane variants need the extra compiles
@@ -253,64 +242,24 @@ def main():
                       rng.choice(hot, args.requests),
                       rng.choice(cold, args.requests))
 
-    ctx = profiler.trace_session() if profiler is not None else None
-    if ctx is not None:
-        ctx.__enter__()
-    try:
+    with (jax.profiler.trace(args.profile_trace_dir)
+          if args.profile_trace_dir else contextlib.nullcontext()):
         if args.mixed:
             serve_mixed(eng, g, stream, args)
         elif args.stream:
             serve_stream(eng, g, stream, args)
         else:
             serve_classic(eng, g, stream, args)
-    finally:
-        if ctx is not None:
-            ctx.__exit__(None, None, None)
+    if args.profile_trace_dir:
+        print(f"jax.profiler trace -> {args.profile_trace_dir}")
 
-    if profiler is not None:
-        summ = profiler.summary()
-        print(f"profile: {summ['sampled']}/{summ['dispatches']} dispatches "
-              f"sampled (rate={summ['sample_rate']:g})")
-        for site, h in sorted(summ["dispatch_latency_s"].items()):
-            print(f"  dispatch[{site}]: n={h['count']} "
-                  f"p50={h['p50'] * 1e3:.2f}ms p99={h['p99'] * 1e3:.2f}ms")
+    if args.profile:
         tel = eng.last_telemetry
         if tel is not None:
             print(f"telemetry: sweeps={tel.sweeps} "
                   f"shard_frontier={tel.shard_frontier().tolist()} "
                   f"frontier_skew={skew(tel.shard_frontier()):.3f} "
                   f"wire_skew={skew(tel.shard_wire_bytes()):.3f}")
-        # the calibration artifact rides the shared repro-bench/1 schema
-        # (benchmarks/common.py lives at the repo root, not under src/)
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from benchmarks.common import write_bench
-
-        st = eng.stats
-        write_bench(args.calib_out, "device_calibration", {
-            "graph": {"scale": args.scale, "th": args.th,
-                      "n": int(g.n), "p": int(eng.pg.p),
-                      "d": int(eng.pg.d), "seed": 0},
-            "requests": args.requests,
-            "n_queries": int(eng.cfg.n_queries),
-            "sample_rate": args.sample_rate,
-            "cells": {"serving": {
-                "sweeps": st.sweeps,
-                "sweep_blocks": st.sweep_blocks,
-                "wire_delegate_bytes": st.wire_delegate_bytes,
-                "wire_nn_bytes": st.wire_nn_bytes,
-                "nn_sparse_sweeps": st.nn_sparse_sweeps,
-                "nn_overflow": st.nn_overflow,
-                "frontier_skew": (skew(tel.shard_frontier())
-                                  if tel is not None else 0.0),
-                "wire_skew": (skew(tel.shard_wire_bytes())
-                              if tel is not None else 0.0),
-                "profile": summ,
-            }},
-        })
-        print(f"calibration artifact -> {args.calib_out}")
-        if args.profile_trace_dir:
-            print(f"jax.profiler trace -> {args.profile_trace_dir}")
 
     if obs is not None:
         obs.export(args.trace_out, args.metrics_out)

@@ -745,62 +745,63 @@ def msbfs_step(
     # strategies bound to this step's partition axes (static at trace time)
     cplan = comm.plan_for(cfg.comm, axis_names)
 
-    # Typed-query liveness gate: a lane with a latched stop (all targets
-    # hit) or at its depth cap contributes no frontier this sweep, so its
-    # push gather, pull scan, nn exchange slots and delegate candidates all
-    # drop out together -- the early exit the distance-limited and
-    # multi-target kinds buy on this substrate.
-    depth = it - state.base_it                               # [W]
-    expand = ~state.lane_stop & (depth < state.depth_cap)    # [W]
+    with jax.named_scope("msbfs.direction"):
+        # Typed-query liveness gate: a lane with a latched stop (all targets
+        # hit) or at its depth cap contributes no frontier this sweep, so its
+        # push gather, pull scan, nn exchange slots and delegate candidates all
+        # drop out together -- the early exit the distance-limited and
+        # multi-target kinds buy on this substrate.
+        depth = it - state.base_it                               # [W]
+        expand = ~state.lane_stop & (depth < state.depth_cap)    # [W]
 
-    nv = pgv.normal_valid[:, None]
-    if cfg.track_levels:
-        unvis_n = (state.level_n == INF_LEVEL) & nv
-        unvis_d = state.level_d == INF_LEVEL
-        frontier_n = (state.level_n == it) & nv & expand[None, :]
-        frontier_d = (state.level_d == it) & expand[None, :]
-    else:
-        # Reachability-only batches: level arrays are bool visited words and
-        # the frontier is explicit state -- no level arithmetic anywhere.
-        unvis_n = ~state.level_n & nv
-        unvis_d = ~state.level_d
-        frontier_n = state.frontier_n & nv & expand[None, :]
-        frontier_d = state.frontier_d & expand[None, :]
+        nv = pgv.normal_valid[:, None]
+        if cfg.track_levels:
+            unvis_n = (state.level_n == INF_LEVEL) & nv
+            unvis_d = state.level_d == INF_LEVEL
+            frontier_n = (state.level_n == it) & nv & expand[None, :]
+            frontier_d = (state.level_d == it) & expand[None, :]
+        else:
+            # Reachability-only batches: level arrays are bool visited words and
+            # the frontier is explicit state -- no level arithmetic anywhere.
+            unvis_n = ~state.level_n & nv
+            unvis_d = ~state.level_d
+            frontier_n = state.frontier_n & nv & expand[None, :]
+            frontier_d = state.frontier_d & expand[None, :]
 
-    deg_nd = _row_degrees(pgv.nd)
-    deg_dn = _row_degrees(pgv.dn)
-    deg_dd = _row_degrees(pgv.dd)
+        deg_nd = _row_degrees(pgv.nd)
+        deg_dn = _row_degrees(pgv.dn)
+        deg_dd = _row_degrees(pgv.dd)
 
-    # ---- per-lane direction decisions (paper Section IV-B, widened) -------
-    fv_dd = _lane_degree_sum(frontier_d, deg_dd)
-    fv_dn = _lane_degree_sum(frontier_d, deg_dn)
-    fv_nd = _lane_degree_sum(frontier_n, deg_nd)
-    if cfg.enable_do:
-        bv_dd = _bv_estimate_lane(
-            _lane_count(frontier_d & pgv.dd_src_mask[:, None]),
-            _lane_count(unvis_d & pgv.dd_src_mask[:, None]),
-            _lane_count(unvis_d & pgv.dd_src_mask[:, None]))
-        bv_dn = _bv_estimate_lane(
-            _lane_count(frontier_d & pgv.dn_src_mask[:, None]),
-            _lane_count(unvis_d & pgv.dn_src_mask[:, None]),
-            _lane_count(unvis_n & pgv.nd_src_mask[:, None]))
-        bv_nd = _bv_estimate_lane(
-            _lane_count(frontier_n & pgv.nd_src_mask[:, None]),
-            _lane_count(unvis_n & pgv.nd_src_mask[:, None]),
-            _lane_count(unvis_d & pgv.dn_src_mask[:, None]))
-        backward = jnp.stack([
-            _decide_direction_lane(state.backward[0], fv_dd, bv_dd, cfg.factor0[0], cfg.factor1[0]),
-            _decide_direction_lane(state.backward[1], fv_dn, bv_dn, cfg.factor0[1], cfg.factor1[1]),
-            _decide_direction_lane(state.backward[2], fv_nd, bv_nd, cfg.factor0[2], cfg.factor1[2]),
-        ])
-        # A converged (or never-seeded) lane must not pull: its frontier word
-        # is empty, so its pull early-exit can never be satisfied and would
-        # rescan full parent lists every remaining sweep. Forward mode with
-        # an empty frontier is free.
-        backward = backward & state.lane_active[None, :]
-    else:
-        backward = jnp.zeros((3, w), dtype=jnp.bool_)
-    bwd_dd, bwd_dn, bwd_nd = backward[0], backward[1], backward[2]
+        # ---- per-lane direction decisions (paper Section IV-B, widened) ---
+        fv_dd = _lane_degree_sum(frontier_d, deg_dd)
+        fv_dn = _lane_degree_sum(frontier_d, deg_dn)
+        fv_nd = _lane_degree_sum(frontier_n, deg_nd)
+        if cfg.enable_do:
+            bv_dd = _bv_estimate_lane(
+                _lane_count(frontier_d & pgv.dd_src_mask[:, None]),
+                _lane_count(unvis_d & pgv.dd_src_mask[:, None]),
+                _lane_count(unvis_d & pgv.dd_src_mask[:, None]))
+            bv_dn = _bv_estimate_lane(
+                _lane_count(frontier_d & pgv.dn_src_mask[:, None]),
+                _lane_count(unvis_d & pgv.dn_src_mask[:, None]),
+                _lane_count(unvis_n & pgv.nd_src_mask[:, None]))
+            bv_nd = _bv_estimate_lane(
+                _lane_count(frontier_n & pgv.nd_src_mask[:, None]),
+                _lane_count(unvis_n & pgv.nd_src_mask[:, None]),
+                _lane_count(unvis_d & pgv.dn_src_mask[:, None]))
+            backward = jnp.stack([
+                _decide_direction_lane(state.backward[0], fv_dd, bv_dd, cfg.factor0[0], cfg.factor1[0]),
+                _decide_direction_lane(state.backward[1], fv_dn, bv_dn, cfg.factor0[1], cfg.factor1[1]),
+                _decide_direction_lane(state.backward[2], fv_nd, bv_nd, cfg.factor0[2], cfg.factor1[2]),
+            ])
+            # A converged (or never-seeded) lane must not pull: its frontier word
+            # is empty, so its pull early-exit can never be satisfied and would
+            # rescan full parent lists every remaining sweep. Forward mode with
+            # an empty frontier is free.
+            backward = backward & state.lane_active[None, :]
+        else:
+            backward = jnp.zeros((3, w), dtype=jnp.bool_)
+        bwd_dd, bwd_dn, bwd_nd = backward[0], backward[1], backward[2]
 
     # Lanes in forward mode push their frontier word; lanes in backward mode
     # pull into their unvisited word. Results are disjoint per lane, so the
@@ -812,254 +813,263 @@ def msbfs_step(
     rb = max(1, ec // max(cfg.pull_chunk, 1)) if ec > 0 else 0
 
     # ---- dd: delegate -> delegate ----------------------------------------
-    push_dd = _push_multi(pgv.dd, frontier_d & ~bwd_dd[None, :], d, ec)
-    pull_dd, work_dd_b = _pull_chunked_multi(
-        pgv.dd, unvis_d & pgv.dd_src_mask[:, None] & bwd_dd[None, :],
-        frontier_d, cfg.pull_chunk, cfg.kernel_pull, rb)
-    cand_dd = push_dd | pull_dd
+    with jax.named_scope("msbfs.dd"):
+        push_dd = _push_multi(pgv.dd, frontier_d & ~bwd_dd[None, :], d, ec)
+        pull_dd, work_dd_b = _pull_chunked_multi(
+            pgv.dd, unvis_d & pgv.dd_src_mask[:, None] & bwd_dd[None, :],
+            frontier_d, cfg.pull_chunk, cfg.kernel_pull, rb)
+        cand_dd = push_dd | pull_dd
 
     # ---- nd: normal -> delegate (pull walks the dn subgraph) --------------
-    push_nd = _push_multi(pgv.nd, frontier_n & ~bwd_nd[None, :], d, ec)
-    pull_nd, work_nd_b = _pull_chunked_multi(
-        pgv.dn, unvis_d & pgv.dn_src_mask[:, None] & bwd_nd[None, :],
-        frontier_n, cfg.pull_chunk, cfg.kernel_pull, rb)
-    cand_nd = push_nd | pull_nd
+    with jax.named_scope("msbfs.nd"):
+        push_nd = _push_multi(pgv.nd, frontier_n & ~bwd_nd[None, :], d, ec)
+        pull_nd, work_nd_b = _pull_chunked_multi(
+            pgv.dn, unvis_d & pgv.dn_src_mask[:, None] & bwd_nd[None, :],
+            frontier_n, cfg.pull_chunk, cfg.kernel_pull, rb)
+        cand_nd = push_nd | pull_nd
 
     # ---- dn: delegate -> normal (pull walks the nd subgraph) --------------
-    push_dn = _push_multi(pgv.dn, frontier_d & ~bwd_dn[None, :], nl, ec)
-    pull_dn, work_dn_b = _pull_chunked_multi(
-        pgv.nd, unvis_n & pgv.nd_src_mask[:, None] & bwd_dn[None, :],
-        frontier_d, cfg.pull_chunk, cfg.kernel_pull, rb)
-    cand_dn = push_dn | pull_dn
+    with jax.named_scope("msbfs.dn"):
+        push_dn = _push_multi(pgv.dn, frontier_d & ~bwd_dn[None, :], nl, ec)
+        pull_dn, work_dn_b = _pull_chunked_multi(
+            pgv.nd, unvis_n & pgv.nd_src_mask[:, None] & bwd_dn[None, :],
+            frontier_d, cfg.pull_chunk, cfg.kernel_pull, rb)
+        cand_dn = push_dn | pull_dn
 
     # ---- nn: normal -> normal, forward only, static slot exchange ---------
     # format (dense lane words / sparse id+word pairs / per-sweep adaptive
     # switch / compressed codec) selected by cfg.comm.nn in the comm layer
-    sa, act_nn_sum = _nn_slots_multi(pgv.nn, frontier_n, plan, ec)
-    rows = jnp.minimum(plan.seg_owner, p - 1)
-    ok = plan.seg_owner < p
-    dense = jnp.zeros((p, plan.cap_peer, w), jnp.bool_).at[rows, plan.seg_pos].max(
-        sa & ok[:, None], mode="drop")
-    recv, nn_bytes, nn_sparse, nn_ovf = comm.nn_exchange_words(
-        cplan, dense, plan.recv_local, nl)
-    sent = jnp.sum(sa.astype(jnp.int32))
+    with jax.named_scope("msbfs.nn.slots"):
+        sa, act_nn_sum = _nn_slots_multi(pgv.nn, frontier_n, plan, ec)
+    with jax.named_scope("msbfs.nn.exchange"):
+        rows = jnp.minimum(plan.seg_owner, p - 1)
+        ok = plan.seg_owner < p
+        dense = jnp.zeros((p, plan.cap_peer, w), jnp.bool_).at[rows, plan.seg_pos].max(
+            sa & ok[:, None], mode="drop")
+        recv, nn_bytes, nn_sparse, nn_ovf = comm.nn_exchange_words(
+            cplan, dense, plan.recv_local, nl)
+        sent = jnp.sum(sa.astype(jnp.int32))
 
     # ---- delegate global reduction: packed-word bitwise-OR combine --------
     # (allgather-fold / ring / hierarchical per cfg.comm.delegate; the
     # local fold optionally runs through the mask_reduce lane-word kernel)
-    cand_d_words = pack_lanes(cand_dd | cand_nd)             # [d, nw]
-    reduced, d_bytes = comm.delegate_combine(cplan, cand_d_words, "or")
-    newly_d = unpack_lanes(reduced, w) & unvis_d
-    new_d_any = jnp.any(newly_d)
+    with jax.named_scope("msbfs.delegate.combine"):
+        cand_d_words = pack_lanes(cand_dd | cand_nd)             # [d, nw]
+        reduced, d_bytes = comm.delegate_combine(cplan, cand_d_words, "or")
+        newly_d = unpack_lanes(reduced, w) & unvis_d
+        new_d_any = jnp.any(newly_d)
 
     # ---- payload plane sweep (static branch: compiled away entirely when
     # cfg.payload is off, like telemetry) -----------------------------------
     if cfg.payload:
-        ident = jnp.int32(PAY_IDENT)
-        wsel = state.pay_weighted                             # [W]
-        # global-id vectors for the synthetic edge weights: this
-        # partition's normal rows (layout formula on the in-trace flat
-        # partition index) and the replicated delegate vids
-        me = comm.codec.self_flat_index(cplan.axes, cplan.sizes)
-        part_base = (me // pgv.p_gpu) + pgv.p_rank * (me % pgv.p_gpu)
-        gid_n = part_base + p * jnp.arange(nl, dtype=jnp.int32)
-        dv = pgv.delegate_vids.reshape(-1).astype(jnp.int32)
-        kd = min(int(dv.shape[0]), d)
-        gid_d = jnp.zeros((d,), jnp.int32)
-        if kd:
-            gid_d = gid_d.at[:kd].set(dv[:kd])
-        # frontier: worklist vertices under the lane's current bucket
-        pfront_n = (state.pay_pending_n & nv
-                    & (state.payload_n < state.pay_bucket[None, :]))
-        pfront_d = (state.pay_pending_d
-                    & (state.payload_d < state.pay_bucket[None, :]))
-        ppush_dd = _push_payload(pgv.dd, pfront_d, state.payload_d,
-                                 gid_d, gid_d, d, wsel, ec)
-        ppush_nd = _push_payload(pgv.nd, pfront_n, state.payload_n,
-                                 gid_n, gid_d, d, wsel, ec)
-        ppush_dn = _push_payload(pgv.dn, pfront_d, state.payload_d,
-                                 gid_d, gid_n, nl, wsel, ec)
-        # nn: per-edge dst gid from the pre-split (owner, local) pair
-        nn_dst_gid = ((pgv.nn_owner // pgv.p_gpu)
-                      + pgv.p_rank * (pgv.nn_owner % pgv.p_gpu)
-                      + p * pgv.nn.cols.astype(jnp.int32)).astype(jnp.int32)
-        sa_pay = _nn_slots_payload(pgv.nn, pfront_n, state.payload_n, gid_n,
-                                   nn_dst_gid, plan, wsel, ec)
-        dense_pay = jnp.full((p, plan.cap_peer, w), ident, jnp.int32).at[
-            rows, plan.seg_pos].min(
-                jnp.where(ok[:, None], sa_pay, ident), mode="drop")
-        recv_pay, pay_nn_bytes, _pay_sparse, pay_nn_ovf = \
-            comm.nn_exchange_payload(cplan, dense_pay, plan.recv_local, nl)
-        # delegate payload combine: native fused pmin under "auto"
-        red_pd, pay_d_bytes = comm.delegate_combine(
-            cplan, jnp.minimum(ppush_dd, ppush_nd), "min")
-        new_pay_d = jnp.minimum(state.payload_d, red_pd)
-        imp_d = new_pay_d < state.payload_d
-        new_pay_n = jnp.where(
-            nv, jnp.minimum(state.payload_n,
-                            jnp.minimum(ppush_dn, recv_pay)), ident)
-        imp_n = new_pay_n < state.payload_n
-        # expanded vertices leave the worklist; improved ones (re)enter it
-        new_pend_n = (state.pay_pending_n & ~pfront_n) | imp_n
-        new_pend_d = (state.pay_pending_d & ~pfront_d) | imp_d
-        # local per-lane convergence rows, folded into the one lane
-        # reduction below instead of adding a collective: pending-any,
-        # under-bucket-any, and the *negated* pending minimum (one pmax
-        # yields a global min for the bucket advance)
-        l_pend = jnp.any(new_pend_n, axis=0) | jnp.any(new_pend_d, axis=0)
-        l_under = (
-            jnp.any(new_pend_n & (new_pay_n < state.pay_bucket[None, :]),
-                    axis=0)
-            | jnp.any(new_pend_d & (new_pay_d < state.pay_bucket[None, :]),
-                      axis=0))
-        minpend = jnp.minimum(
-            jnp.min(jnp.where(new_pend_n, new_pay_n, ident), axis=0),
-            jnp.min(jnp.where(new_pend_d, new_pay_d, ident), axis=0))
-        pay_rows = jnp.stack([l_pend.astype(jnp.int32),
-                              l_under.astype(jnp.int32), -minpend])
+        with jax.named_scope("msbfs.payload"):
+            ident = jnp.int32(PAY_IDENT)
+            wsel = state.pay_weighted                             # [W]
+            # global-id vectors for the synthetic edge weights: this
+            # partition's normal rows (layout formula on the in-trace flat
+            # partition index) and the replicated delegate vids
+            me = comm.codec.self_flat_index(cplan.axes, cplan.sizes)
+            part_base = (me // pgv.p_gpu) + pgv.p_rank * (me % pgv.p_gpu)
+            gid_n = part_base + p * jnp.arange(nl, dtype=jnp.int32)
+            dv = pgv.delegate_vids.reshape(-1).astype(jnp.int32)
+            kd = min(int(dv.shape[0]), d)
+            gid_d = jnp.zeros((d,), jnp.int32)
+            if kd:
+                gid_d = gid_d.at[:kd].set(dv[:kd])
+            # frontier: worklist vertices under the lane's current bucket
+            pfront_n = (state.pay_pending_n & nv
+                        & (state.payload_n < state.pay_bucket[None, :]))
+            pfront_d = (state.pay_pending_d
+                        & (state.payload_d < state.pay_bucket[None, :]))
+            ppush_dd = _push_payload(pgv.dd, pfront_d, state.payload_d,
+                                     gid_d, gid_d, d, wsel, ec)
+            ppush_nd = _push_payload(pgv.nd, pfront_n, state.payload_n,
+                                     gid_n, gid_d, d, wsel, ec)
+            ppush_dn = _push_payload(pgv.dn, pfront_d, state.payload_d,
+                                     gid_d, gid_n, nl, wsel, ec)
+            # nn: per-edge dst gid from the pre-split (owner, local) pair
+            nn_dst_gid = ((pgv.nn_owner // pgv.p_gpu)
+                          + pgv.p_rank * (pgv.nn_owner % pgv.p_gpu)
+                          + p * pgv.nn.cols.astype(jnp.int32)).astype(jnp.int32)
+            sa_pay = _nn_slots_payload(pgv.nn, pfront_n, state.payload_n, gid_n,
+                                       nn_dst_gid, plan, wsel, ec)
+            dense_pay = jnp.full((p, plan.cap_peer, w), ident, jnp.int32).at[
+                rows, plan.seg_pos].min(
+                    jnp.where(ok[:, None], sa_pay, ident), mode="drop")
+            recv_pay, pay_nn_bytes, _pay_sparse, pay_nn_ovf = \
+                comm.nn_exchange_payload(cplan, dense_pay, plan.recv_local, nl)
+            # delegate payload combine: native fused pmin under "auto"
+            red_pd, pay_d_bytes = comm.delegate_combine(
+                cplan, jnp.minimum(ppush_dd, ppush_nd), "min")
+            new_pay_d = jnp.minimum(state.payload_d, red_pd)
+            imp_d = new_pay_d < state.payload_d
+            new_pay_n = jnp.where(
+                nv, jnp.minimum(state.payload_n,
+                                jnp.minimum(ppush_dn, recv_pay)), ident)
+            imp_n = new_pay_n < state.payload_n
+            # expanded vertices leave the worklist; improved ones (re)enter it
+            new_pend_n = (state.pay_pending_n & ~pfront_n) | imp_n
+            new_pend_d = (state.pay_pending_d & ~pfront_d) | imp_d
+            # local per-lane convergence rows, folded into the one lane
+            # reduction below instead of adding a collective: pending-any,
+            # under-bucket-any, and the *negated* pending minimum (one pmax
+            # yields a global min for the bucket advance)
+            l_pend = jnp.any(new_pend_n, axis=0) | jnp.any(new_pend_d, axis=0)
+            l_under = (
+                jnp.any(new_pend_n & (new_pay_n < state.pay_bucket[None, :]),
+                        axis=0)
+                | jnp.any(new_pend_d & (new_pay_d < state.pay_bucket[None, :]),
+                          axis=0))
+            minpend = jnp.minimum(
+                jnp.min(jnp.where(new_pend_n, new_pay_n, ident), axis=0),
+                jnp.min(jnp.where(new_pend_d, new_pay_d, ident), axis=0))
+            pay_rows = jnp.stack([l_pend.astype(jnp.int32),
+                                  l_under.astype(jnp.int32), -minpend])
 
     # ---- level / visited updates ------------------------------------------
-    newly_n = (cand_dn | recv) & unvis_n
-    if cfg.track_levels:
-        new_level_d = jnp.where(newly_d, it + 1, state.level_d)
-        new_level_n = jnp.where(newly_n, it + 1, state.level_n)
-        new_frontier_n, new_frontier_d = state.frontier_n, state.frontier_d
-    else:
-        new_level_d = state.level_d | newly_d                # visited words
-        new_level_n = state.level_n | newly_n
-        new_frontier_n, new_frontier_d = newly_n, newly_d
-
-    # per-lane convergence: lane q stays live iff it marked a new vertex on
-    # some partition this sweep (delegate updates are already global). The
-    # target word rides the same one-word collective: flag 1 is "lane q
-    # still has an unvisited target somewhere".
-    if cfg.enable_targets:
-        unhit_n = jnp.any(state.target_n & unvis_n & ~newly_n, axis=0)
-        flags = jnp.stack([jnp.any(newly_n, axis=0), unhit_n])   # [2, W]
-        if cfg.payload:
-            red_all = comm.lane_fold_reduce(
-                jnp.concatenate([flags.astype(jnp.int32), pay_rows]),
-                axis_names)
-            red = red_all[:2] > 0
+    with jax.named_scope("msbfs.update"):
+        newly_n = (cand_dn | recv) & unvis_n
+        if cfg.track_levels:
+            new_level_d = jnp.where(newly_d, it + 1, state.level_d)
+            new_level_n = jnp.where(newly_n, it + 1, state.level_n)
+            new_frontier_n, new_frontier_d = state.frontier_n, state.frontier_d
         else:
-            red = comm.lane_any_reduce(flags, axis_names)
-        unhit = red[1] | jnp.any(state.target_d & unvis_d & ~newly_d, axis=0)
-        upd_global = red[0]
-        stop_targets = state.has_targets & ~unhit
-    else:
-        if cfg.payload:
-            red_all = comm.lane_fold_reduce(jnp.concatenate(
-                [jnp.any(newly_n, axis=0).astype(jnp.int32)[None],
-                 pay_rows]), axis_names)
-            upd_global = red_all[0] > 0
-        else:
-            upd_global = comm.lane_any_reduce(jnp.any(newly_n, axis=0),
-                                              axis_names)
-        stop_targets = jnp.zeros_like(state.lane_stop)
-    # latch the stop: every target covered, or the next sweep would exceed
-    # the lane's depth cap
-    new_stop = (state.lane_stop | stop_targets
-                | (depth + 1 >= state.depth_cap))
-    lane_upd = (upd_global | jnp.any(newly_d, axis=0)) & ~new_stop
-    if cfg.payload:
-        # payload lanes stay live while pending work remains anywhere (their
-        # bit planes are empty, so the bit rows never fire for them). The
-        # same fold resolves the delta-stepping bucket advance: pending
-        # exists but none under the current bucket -> jump the bucket to the
-        # global pending minimum's next bucket boundary. Components lanes
-        # (delta = bucket = +inf) never advance: every finite pending value
-        # is already under the bucket.
-        g_pend = red_all[-3] > 0
-        g_under = red_all[-2] > 0
-        g_minpend = -red_all[-1]
-        lane_upd = lane_upd | g_pend
-        dstep = jnp.maximum(state.pay_delta, 1)
-        nb = (jnp.clip(g_minpend, 0, PAY_IDENT) // dstep + 1) * dstep
-        new_bucket = jnp.where(g_pend & ~g_under,
-                               jnp.minimum(nb, jnp.int32(PAY_IDENT)),
-                               state.pay_bucket)
-    updated = jnp.any(lane_upd)
+            new_level_d = state.level_d | newly_d                # visited words
+            new_level_n = state.level_n | newly_n
+            new_frontier_n, new_frontier_d = newly_n, newly_d
 
-    # ---- statistics --------------------------------------------------------
-    w_fwd = (
-        jnp.sum(jnp.where(bwd_dd, 0, fv_dd)) + jnp.sum(jnp.where(bwd_nd, 0, fv_nd))
-        + jnp.sum(jnp.where(bwd_dn, 0, fv_dn))
-    )
-    if cfg.track_levels:
-        # exact per-edge-lane push count; the reachability-only variant
-        # keeps the frontier degree-sum estimates above instead of
-        # materializing the [E, W] int32 count
-        w_fwd = w_fwd + act_nn_sum
-    w_bwd = work_dd_b + work_nd_b + work_dn_b
-    slot = jnp.clip(it, 0, cfg.max_iters - 1)
-    # ---- device-plane sweep telemetry (static branch: the disabled path
-    # returns the zero-size carry untouched and XLA compiles all of this
-    # away -- the expand-gated frontier masks and the direction word are
-    # already live values, so telemetry adds no new collective, no new
-    # host sync, only its own accumulation) -------------------------------
-    if cfg.telemetry:
-        tm_frontier_n = state.tm_frontier_n.at[slot].add(
-            jnp.sum(frontier_n.astype(jnp.int32)))
-        tm_frontier_d = state.tm_frontier_d.at[slot].add(
-            jnp.sum(frontier_d.astype(jnp.int32)))
-        tm_backward = state.tm_backward.at[slot].set(pack_lanes(backward))
-    else:
-        tm_frontier_n = state.tm_frontier_n
-        tm_frontier_d = state.tm_frontier_d
-        tm_backward = state.tm_backward
-    if cfg.payload:
-        wire_pay_delegate = state.wire_pay_delegate.at[slot].add(
-            jnp.int32(pay_d_bytes))
-        wire_pay_nn = state.wire_pay_nn.at[slot].add(pay_nn_bytes)
-        nn_ovf = nn_ovf + pay_nn_ovf       # overflow guard covers both planes
-    else:
-        new_pay_n, new_pay_d = state.payload_n, state.payload_d
-        new_pend_n, new_pend_d = state.pay_pending_n, state.pay_pending_d
-        new_bucket = state.pay_bucket
-        wire_pay_delegate = state.wire_pay_delegate
-        wire_pay_nn = state.wire_pay_nn
-    return MSBFSState(
-        level_n=new_level_n,
-        level_d=new_level_d,
-        backward=backward,
-        it=it + 1,
-        done=~updated,
-        lane_active=lane_upd,
-        base_it=state.base_it,
-        lane_stop=new_stop,
-        depth_cap=state.depth_cap,
-        has_targets=state.has_targets,
-        target_n=state.target_n,
-        target_d=state.target_d,
-        frontier_n=new_frontier_n,
-        frontier_d=new_frontier_d,
-        work_fwd=state.work_fwd.at[slot].set(w_fwd),
-        work_bwd=state.work_bwd.at[slot].set(w_bwd),
-        nn_sent=state.nn_sent.at[slot].set(sent),
-        delegate_round=state.delegate_round.at[slot].set(new_d_any.astype(jnp.int32)),
-        wire_delegate=state.wire_delegate.at[slot].add(jnp.int32(d_bytes)),
-        wire_nn=state.wire_nn.at[slot].add(nn_bytes),
-        nn_sparse=state.nn_sparse.at[slot].add(nn_sparse),
-        nn_overflow=state.nn_overflow.at[slot].add(nn_ovf),
-        tm_frontier_n=tm_frontier_n,
-        tm_frontier_d=tm_frontier_d,
-        tm_backward=tm_backward,
-        payload_n=new_pay_n,
-        payload_d=new_pay_d,
-        pay_pending_n=new_pend_n,
-        pay_pending_d=new_pend_d,
-        pay_bucket=new_bucket,
-        pay_delta=state.pay_delta,
-        pay_weighted=state.pay_weighted,
-        wire_pay_delegate=wire_pay_delegate,
-        wire_pay_nn=wire_pay_nn,
-    )
+        # per-lane convergence: lane q stays live iff it marked a new vertex on
+        # some partition this sweep (delegate updates are already global). The
+        # target word rides the same one-word collective: flag 1 is "lane q
+        # still has an unvisited target somewhere".
+        if cfg.enable_targets:
+            unhit_n = jnp.any(state.target_n & unvis_n & ~newly_n, axis=0)
+            flags = jnp.stack([jnp.any(newly_n, axis=0), unhit_n])   # [2, W]
+            if cfg.payload:
+                red_all = comm.lane_fold_reduce(
+                    jnp.concatenate([flags.astype(jnp.int32), pay_rows]),
+                    axis_names)
+                red = red_all[:2] > 0
+            else:
+                red = comm.lane_any_reduce(flags, axis_names)
+            unhit = red[1] | jnp.any(state.target_d & unvis_d & ~newly_d, axis=0)
+            upd_global = red[0]
+            stop_targets = state.has_targets & ~unhit
+        else:
+            if cfg.payload:
+                red_all = comm.lane_fold_reduce(jnp.concatenate(
+                    [jnp.any(newly_n, axis=0).astype(jnp.int32)[None],
+                     pay_rows]), axis_names)
+                upd_global = red_all[0] > 0
+            else:
+                upd_global = comm.lane_any_reduce(jnp.any(newly_n, axis=0),
+                                                  axis_names)
+            stop_targets = jnp.zeros_like(state.lane_stop)
+        # latch the stop: every target covered, or the next sweep would exceed
+        # the lane's depth cap
+        new_stop = (state.lane_stop | stop_targets
+                    | (depth + 1 >= state.depth_cap))
+        lane_upd = (upd_global | jnp.any(newly_d, axis=0)) & ~new_stop
+        if cfg.payload:
+            # payload lanes stay live while pending work remains anywhere (their
+            # bit planes are empty, so the bit rows never fire for them). The
+            # same fold resolves the delta-stepping bucket advance: pending
+            # exists but none under the current bucket -> jump the bucket to the
+            # global pending minimum's next bucket boundary. Components lanes
+            # (delta = bucket = +inf) never advance: every finite pending value
+            # is already under the bucket.
+            g_pend = red_all[-3] > 0
+            g_under = red_all[-2] > 0
+            g_minpend = -red_all[-1]
+            lane_upd = lane_upd | g_pend
+            dstep = jnp.maximum(state.pay_delta, 1)
+            nb = (jnp.clip(g_minpend, 0, PAY_IDENT) // dstep + 1) * dstep
+            new_bucket = jnp.where(g_pend & ~g_under,
+                                   jnp.minimum(nb, jnp.int32(PAY_IDENT)),
+                                   state.pay_bucket)
+        updated = jnp.any(lane_upd)
+
+        # ---- statistics ----------------------------------------------------
+        w_fwd = (
+            jnp.sum(jnp.where(bwd_dd, 0, fv_dd)) + jnp.sum(jnp.where(bwd_nd, 0, fv_nd))
+            + jnp.sum(jnp.where(bwd_dn, 0, fv_dn))
+        )
+        if cfg.track_levels:
+            # exact per-edge-lane push count; the reachability-only variant
+            # keeps the frontier degree-sum estimates above instead of
+            # materializing the [E, W] int32 count
+            w_fwd = w_fwd + act_nn_sum
+        w_bwd = work_dd_b + work_nd_b + work_dn_b
+        slot = jnp.clip(it, 0, cfg.max_iters - 1)
+        # ---- device-plane sweep telemetry (static branch: the disabled path
+        # returns the zero-size carry untouched and XLA compiles all of this
+        # away -- the expand-gated frontier masks and the direction word are
+        # already live values, so telemetry adds no new collective, no new
+        # host sync, only its own accumulation) -------------------------------
+        if cfg.telemetry:
+            tm_frontier_n = state.tm_frontier_n.at[slot].add(
+                jnp.sum(frontier_n.astype(jnp.int32)))
+            tm_frontier_d = state.tm_frontier_d.at[slot].add(
+                jnp.sum(frontier_d.astype(jnp.int32)))
+            tm_backward = state.tm_backward.at[slot].set(pack_lanes(backward))
+        else:
+            tm_frontier_n = state.tm_frontier_n
+            tm_frontier_d = state.tm_frontier_d
+            tm_backward = state.tm_backward
+        if cfg.payload:
+            wire_pay_delegate = state.wire_pay_delegate.at[slot].add(
+                jnp.int32(pay_d_bytes))
+            wire_pay_nn = state.wire_pay_nn.at[slot].add(pay_nn_bytes)
+            nn_ovf = nn_ovf + pay_nn_ovf       # overflow guard covers both planes
+        else:
+            new_pay_n, new_pay_d = state.payload_n, state.payload_d
+            new_pend_n, new_pend_d = state.pay_pending_n, state.pay_pending_d
+            new_bucket = state.pay_bucket
+            wire_pay_delegate = state.wire_pay_delegate
+            wire_pay_nn = state.wire_pay_nn
+        return MSBFSState(
+            level_n=new_level_n,
+            level_d=new_level_d,
+            backward=backward,
+            it=it + 1,
+            done=~updated,
+            lane_active=lane_upd,
+            base_it=state.base_it,
+            lane_stop=new_stop,
+            depth_cap=state.depth_cap,
+            has_targets=state.has_targets,
+            target_n=state.target_n,
+            target_d=state.target_d,
+            frontier_n=new_frontier_n,
+            frontier_d=new_frontier_d,
+            work_fwd=state.work_fwd.at[slot].set(w_fwd),
+            work_bwd=state.work_bwd.at[slot].set(w_bwd),
+            nn_sent=state.nn_sent.at[slot].set(sent),
+            delegate_round=state.delegate_round.at[slot].set(new_d_any.astype(jnp.int32)),
+            wire_delegate=state.wire_delegate.at[slot].add(jnp.int32(d_bytes)),
+            wire_nn=state.wire_nn.at[slot].add(nn_bytes),
+            nn_sparse=state.nn_sparse.at[slot].add(nn_sparse),
+            nn_overflow=state.nn_overflow.at[slot].add(nn_ovf),
+            tm_frontier_n=tm_frontier_n,
+            tm_frontier_d=tm_frontier_d,
+            tm_backward=tm_backward,
+            payload_n=new_pay_n,
+            payload_d=new_pay_d,
+            pay_pending_n=new_pend_n,
+            pay_pending_d=new_pend_d,
+            pay_bucket=new_bucket,
+            pay_delta=state.pay_delta,
+            pay_weighted=state.pay_weighted,
+            wire_pay_delegate=wire_pay_delegate,
+            wire_pay_nn=wire_pay_nn,
+        )
 
 
 # -----------------------------------------------------------------------------
 # Lane retirement / refill
 
 
+@jax.named_scope("msbfs.reseed")
 def _reseed_lanes_impl(
     state: MSBFSState,
     lane_mask: jnp.ndarray,       # [W] bool: lanes to retire + reseed
@@ -1327,13 +1337,16 @@ def _block_loop(step_fn, args, state: MSBFSState, watch: jnp.ndarray, k: int):
     freezes itself instead of corrupting the schedule.
     """
 
+    @jax.named_scope("msbfs.block")
     def cond(carry):
         s, i = carry
         return (i < k) & ~jnp.any(watch[None, :] & ~s.lane_active)
 
     def body(carry):
         s, i = carry
-        return step_fn(args, s), i + jnp.int32(1)
+        s = step_fn(args, s)
+        with jax.named_scope("msbfs.block"):
+            return s, i + jnp.int32(1)
 
     s, _ = lax.while_loop(cond, body, (state, jnp.int32(0)))
     return s
@@ -1348,11 +1361,16 @@ def make_msbfs_block_emulated(cfg: MSBFSConfig, k: int, donate: bool = False):
     deleted on every backend, so only pass a state nothing else holds."""
     step = _vmapped_step(cfg)
 
-    def block(pgv_stacked, plan_stacked, state, watch):
+    # The jitted function's name names the compiled module, and unlike op
+    # metadata (the ``msbfs.*`` scopes) it is part of the persistent
+    # compilation cache's key: an executable cached by a build of the
+    # block without those scopes is never loaded, stale names and all, in
+    # this one's place.
+    def msbfs_block(pgv_stacked, plan_stacked, state, watch):
         return _block_loop(lambda a, s: step(a[0], a[1], s),
                            (pgv_stacked, plan_stacked), state, watch, k)
 
-    return jax.jit(block, donate_argnums=(2,) if donate else ())
+    return jax.jit(msbfs_block, donate_argnums=(2,) if donate else ())
 
 
 def make_sharded_msbfs_block(mesh, partition_axes, cfg: MSBFSConfig, k: int,
@@ -1362,11 +1380,11 @@ def make_sharded_msbfs_block(mesh, partition_axes, cfg: MSBFSConfig, k: int,
     same stop-at-retirement contract."""
     step = _make_sharded_step(mesh, tuple(partition_axes), cfg)
 
-    def block(pgv, plan, state, watch):
+    def msbfs_block(pgv, plan, state, watch):   # named as the emulated one
         return _block_loop(lambda a, s: step(a[0], a[1], s),
                            (pgv, plan), state, watch, k)
 
-    return jax.jit(block, donate_argnums=(2,) if donate else ())
+    return jax.jit(msbfs_block, donate_argnums=(2,) if donate else ())
 
 
 def _gather_lane_columns(pg: PartitionedGraph, state: MSBFSState, lanes):

@@ -11,15 +11,7 @@ plus MODEL_FLOPS (analytic useful compute, 6·N·D train / 2·N·D inference,
 active params for MoE) and the useful-compute ratio that catches
 remat/redundancy waste. Emits the EXPERIMENTS.md tables.
 
-``--calib CALIB_device.json`` additionally renders the *measured* prior
-table from a ``scripts/profile_sweep.py`` artifact: per (comm strategy x
-nn format x sweep_block) cell, the measured block-dispatch latency next
-to the exact wire-byte counters and per-shard skew -- the empirical side
-the analytic collective term above can be checked against, and the seed
-data the comm-strategy autotuner (ROADMAP item 4) consumes.
-
 Usage: PYTHONPATH=src python -m repro.launch.roofline [--dir runs/dryrun]
-       [--calib CALIB_device.json]
 """
 from __future__ import annotations
 
@@ -202,47 +194,11 @@ def markdown_table(rows: list) -> str:
     return hdr + "\n".join(lines) + "\n"
 
 
-def load_calibration(path: str) -> dict:
-    """Read a ``profile_sweep.py`` artifact's ``device_calibration``
-    section (``repro-bench/1`` schema; raises KeyError if absent)."""
-    doc = json.load(open(path))
-    return doc["benchmarks"]["device_calibration"]
-
-
-def calib_table(calib: dict) -> str:
-    """Markdown table of measured priors per calibration cell: block p50
-    latency, throughput, exact wire volume split, and shard skew."""
-    g = calib.get("graph", {})
-    hdr = (f"measured device calibration (scale={g.get('scale')} "
-           f"p={g.get('p')} d={g.get('d')} requests={calib.get('requests')} "
-           f"W={calib.get('n_queries')}):\n"
-           "| cell | block p50 s | block p99 s | qps | wire delegate B "
-           "| wire nn B | sparse sweeps | frontier skew |\n"
-           "|---|---|---|---|---|---|---|---|\n")
-    lines = []
-    for key in sorted(calib.get("cells", {})):
-        c = calib["cells"][key]
-        lat = c.get("profile", {}).get("dispatch_latency_s", {})
-        blk = lat.get("block") or next(iter(lat.values()), {})
-        lines.append(
-            f"| {key} | {fmt_s(blk.get('p50'))} | {fmt_s(blk.get('p99'))} "
-            f"| {c.get('qps', 0):.1f} | {c.get('wire_delegate_bytes', 0)} "
-            f"| {c.get('wire_nn_bytes', 0)} | {c.get('nn_sparse_sweeps', 0)} "
-            f"| {c.get('frontier_skew', 0):.3f} |")
-    return hdr + "\n".join(lines) + "\n"
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="runs/dryrun")
     ap.add_argument("--mesh", default=None, choices=[None, "16x16", "2x16x16"])
-    ap.add_argument("--calib", default=None,
-                    help="CALIB_device.json from scripts/profile_sweep.py: "
-                         "print the measured-prior table and exit")
     args = ap.parse_args()
-    if args.calib:
-        print(calib_table(load_calibration(args.calib)))
-        return
     records = load_records(args.dir)
     corrected = _scan_corrected(records)
     rows = []

@@ -22,11 +22,13 @@ tracer + disabled registry, both handing out shared no-op objects.
 Both clocks are injectable (``Observability(clock=...)``) so tests drive
 deterministic timestamps -- the same pattern as ``serve/cache.py``.
 
-Two device-plane companions live alongside the host-plane pair:
-``obs/device.py`` harvests the in-jit sweep telemetry carry into
-``device.shard.<i>.*`` imbalance metrics, and ``obs/profile.py`` samples
-dispatch->ready latencies (``BFSServeEngine(profile=...)``) for the
-``CALIB_device.json`` calibration artifact.
+While a ``jax.profiler`` capture runs, every span of an enabled tracer
+also lands on the profiler's host plane, on the device planes' clock
+(``obs/trace.py``); the traversal step names its phases with
+``jax.named_scope`` (``msbfs.*``, ``core/msbfs.py``), so device time in
+the same capture is attributed to them. One device-plane companion lives
+alongside the host-plane pair: ``obs/device.py`` harvests the in-jit
+sweep telemetry carry into ``device.shard.<i>.*`` imbalance metrics.
 
 See ``README.md`` in this package for the event taxonomy, exporter usage,
 and how to open a trace in Perfetto.
@@ -41,7 +43,6 @@ from .metrics import (BYTES_BUCKETS, LATENCY_BUCKETS, NULL_INSTRUMENT,
                       RATIO_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, exp_buckets, sanitize_label,
                       shard_metric, tenant_metric)
-from .profile import NULL_PROFILER, DispatchProfiler, as_profiler
 from .trace import NULL_SPAN, TraceEvent, Tracer
 
 
@@ -75,10 +76,10 @@ NULL_OBS = Observability(enabled=False)
 
 
 __all__ = [
-    "BYTES_BUCKETS", "Counter", "DispatchProfiler", "Gauge", "Histogram",
-    "LATENCY_BUCKETS", "MetricsRegistry", "NULL_INSTRUMENT", "NULL_OBS",
-    "NULL_PROFILER", "NULL_SPAN", "Observability", "RATIO_BUCKETS",
-    "SweepTelemetry", "TraceEvent", "Tracer", "as_profiler", "exp_buckets",
+    "BYTES_BUCKETS", "Counter", "Gauge", "Histogram", "LATENCY_BUCKETS",
+    "MetricsRegistry", "NULL_INSTRUMENT", "NULL_OBS", "NULL_SPAN",
+    "Observability", "RATIO_BUCKETS", "SweepTelemetry", "TraceEvent",
+    "Tracer", "exp_buckets",
     "export_shard_metrics", "harvest_telemetry", "sanitize_label",
     "shard_metric", "skew", "tenant_metric",
 ]

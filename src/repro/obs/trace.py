@@ -13,6 +13,12 @@ buffer.  Design constraints, in order:
   never touches device arrays, so a traced serving run executes the exact
   same sweeps (and ``ServeStats`` counters) as an untraced one -- pinned
   by ``tests/test_obs.py``.
+* **One clock with the device.** An enabled tracer's every span also opens
+  a ``jax.profiler.TraceAnnotation`` of the same name and arguments, so
+  while a ``jax.profiler`` capture runs, the span lands on the profiler's
+  host plane, on the clock of its device planes: an idle gap on the device
+  can be matched to the host span it falls under. Outside a capture the
+  annotation is a no-op. Instants stay in the ring buffer only.
 * **Bounded memory.** Events land in a ring buffer (``capacity`` events);
   when full, the oldest events are overwritten and counted in
   ``dropped`` -- a long-lived serving process can leave tracing on.
@@ -33,6 +39,8 @@ import json
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+import jax
 
 
 @dataclass(frozen=True)
@@ -72,9 +80,11 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, inside a
+    profiler annotation of the same name (opened with the span's arguments
+    at entry)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -87,6 +97,8 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self):
+        self._note = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._note.__enter__()
         self._depth = self._tracer._depth
         self._tracer._depth += 1
         self._t0 = self._tracer._clock()
@@ -94,6 +106,7 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = self._tracer._clock()
+        self._note.__exit__(*exc)
         self._tracer._depth = self._depth
         self._tracer._record(TraceEvent(
             name=self.name, ts=self._t0, dur=t1 - self._t0,
@@ -137,7 +150,8 @@ class Tracer:
     # -- recording API ------------------------------------------------------
     def span(self, name: str, **args):
         """Context manager timing a named interval; nesting is tracked so
-        exported traces reconstruct the call structure."""
+        exported traces reconstruct the call structure, and the interval
+        is mirrored onto a running ``jax.profiler`` capture."""
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, args)

@@ -54,7 +54,7 @@ from repro.core.partition import partition_graph
 from repro.core.types import COOGraph, PartitionLayout, PartitionedGraph
 from repro.core.weights import SSSP_DELTA
 from repro.obs import (BYTES_BUCKETS, NULL_OBS, RATIO_BUCKETS, Observability,
-                       as_profiler, export_shard_metrics, harvest_telemetry)
+                       export_shard_metrics, harvest_telemetry)
 
 from .batcher import LaneScheduler
 from .cache import LRUCache
@@ -228,6 +228,7 @@ class _Session:
     cfg: M.MSBFSConfig
     reach_fast: bool
     sched: LaneScheduler
+    sid: int                         # session id: every span's ``session``
     state: Any                       # device MSBFSState (latest processed)
     step_once: Any                   # per-sweep runner (sync driver)
     block: Any = None                # fused k-sweep runner (pipelined)
@@ -337,13 +338,6 @@ class BFSServeEngine:
         completion / session close -- zero extra syncs) into
         ``self.last_telemetry`` and the ``device.shard.<i>.*`` imbalance
         metrics (see ``obs/device.py``).
-    profile : dispatch-latency profiling (``obs/profile.py``): pass a
-        :class:`repro.obs.DispatchProfiler`, ``True`` (bracket every
-        dispatch with ``block_until_ready``), or a float sample rate.
-        Sampled dispatches measure dispatch->results-ready latency per
-        dispatch site (``batch`` / ``sweep`` / ``block``); the traversal
-        schedule and every ``ServeStats`` counter stay bit-identical --
-        only host timing moves. Default off (a shared null passthrough).
     reuse_components : memoize reachability answers *per connected
         component*: on an undirected graph the reachable set is the
         source's component, so every later REACHABILITY query from an
@@ -381,11 +375,10 @@ class BFSServeEngine:
         specialize_reachability: bool = True,
         reuse_components: bool = True,
         obs: Observability | None = None,
-        profile=None,
         runner_cache: dict | None = None,
     ):
         self.obs = obs if obs is not None else NULL_OBS
-        self.profiler = as_profiler(profile, obs=self.obs)
+        self._sessions = 0           # ids handed to batches and sessions
         self.last_telemetry = None   # latest harvested SweepTelemetry
         if pg is None:
             if graph is None:
@@ -564,15 +557,24 @@ class BFSServeEngine:
                 and all(q.kind is QueryKind.REACHABILITY for q in queries))
 
     def _gather_rows(self, cfg: M.MSBFSConfig, reach_fast: bool, state,
-                     lanes, items) -> list:
+                     lanes, items, sid: int) -> list:
         """Kind-aware per-lane result rows for ``lanes`` (aligned with the
         typed ``items``): payload kinds read their payload-plane column,
         everything else the level (or packed-reach) columns -- at most one
-        gather per plane leaves the device."""
+        copy per plane leaves the device. The copy (which waits for the
+        block that produced the planes) runs under its own
+        ``serve.gather.fetch`` span, so the caller's gather span keeps the
+        host-side assembly and unpack as its self time."""
+        pay = [not reach_fast and cfg.payload
+               and as_query(it).kind in PAYLOAD_KINDS for it in items]
+        leaves = (() if all(pay) else ("level_n", "level_d", "base_it")) + (
+            ("payload_n", "payload_d") if any(pay) else ())
+        with self.obs.trace.span("serve.gather.fetch", session=sid,
+                                 lanes=len(lanes)):
+            host = jax.device_get({k: getattr(state, k) for k in leaves})
+        state = _dc_replace(state, **host)
         if reach_fast:
             return list(M.gather_reachable_multi(self.pg, state, lanes=lanes))
-        pay = [cfg.payload and as_query(it).kind in PAYLOAD_KINDS
-               for it in items]
         rows = (M.gather_levels_multi(self.pg, state, lanes=lanes)
                 if not all(pay) else None)
         prows = (M.gather_payload_multi(self.pg, state, lanes=lanes)
@@ -691,18 +693,20 @@ class BFSServeEngine:
         cfg = self._session_cfg(queries)
         run_full, _ = self._runner_pair(cfg)
         sweeps = 0
-        with self.obs.trace.span("serve.batch", n=len(queries),
+        sid = self._next_session()
+        with self.obs.trace.span("serve.batch", session=sid, n=len(queries),
                                  reach_fast=reach_fast) as sp:
             st = self._put(M.init_multi_state(
                 self.pg, [q.source for q in queries], cfg,
                 depth_caps=[q.depth_cap for q in queries],
                 targets=[q.targets for q in queries],
                 payload_modes=[q.payload_mode for q in queries]))
-            out = self.profiler.timed("batch", run_full,
-                                      self.pgv, self.plan, st)
-            with self.obs.trace.span("serve.gather", lanes=len(queries)):
+            out = run_full(self.pgv, self.plan, st)
+            with self.obs.trace.span("serve.gather", session=sid,
+                                     lanes=len(queries)):
                 rows = self._gather_rows(cfg, reach_fast, out,
-                                         np.arange(len(queries)), queries)
+                                         np.arange(len(queries)), queries,
+                                         sid)
             if self.obs.enabled:
                 # host-side introspection only (the run already finished):
                 # never changes the traversal schedule or any counter
@@ -825,8 +829,9 @@ class BFSServeEngine:
             return {}
         self._validate_queries(queries)
         with self.obs.trace.span("serve.refill_drain", n=len(queries),
-                                 overlap=self.overlap):
+                                 overlap=self.overlap) as sp:
             sess = self._open_session(queries)
+            sp.set(session=sess.sid)
             if self.overlap:
                 while sess.sched.n_busy:
                     self._pipeline_advance(sess)
@@ -861,11 +866,13 @@ class BFSServeEngine:
                 cfg = self._payload_cfg(cfg)
         else:
             cfg = self._session_cfg(queries)
-        with self.obs.trace.span("serve.session.open", n=len(queries),
-                                 stream=stream, reach_fast=reach_fast):
+        sid = self._next_session()
+        with self.obs.trace.span("serve.session.open", session=sid,
+                                 n=len(queries), stream=stream,
+                                 reach_fast=reach_fast):
             _, step_once = self._runner_pair(cfg)
             sess = _Session(
-                cfg=cfg, reach_fast=reach_fast,
+                cfg=cfg, reach_fast=reach_fast, sid=sid,
                 sched=LaneScheduler(w, pending=() if stream else queries,
                                     obs=self.obs),
                 state=self._put(M.init_multi_state(self.pg, [], cfg)),
@@ -884,6 +891,12 @@ class BFSServeEngine:
             self.stats.lanes_padded += max(0, w - len(queries))
         return sess
 
+    def _next_session(self) -> int:
+        """A fresh id for a batch or session (the ``session`` argument of
+        its spans, so that spans of one key set share an identifier)."""
+        self._sessions += 1
+        return self._sessions
+
     def _reseed(self, sess: _Session, assignments):
         desc = self._seed_descriptors(assignments, payload=sess.cfg.payload)
         reseed = (M.reseed_lanes_donated if sess.exclusive
@@ -896,8 +909,8 @@ class BFSServeEngine:
         mid-flight ``refills``."""
         fresh = sess.sched.fill_idle()
         if fresh:
-            with self.obs.trace.span("serve.reseed", lanes=len(fresh),
-                                     initial=initial):
+            with self.obs.trace.span("serve.reseed", session=sess.sid,
+                                     lanes=len(fresh), initial=initial):
                 sess.state = self._reseed(sess, fresh)
             sess.exclusive = True
             self.stats.lanes_used += len(fresh)
@@ -934,14 +947,15 @@ class BFSServeEngine:
         fin_lanes = np.nonzero(finished)[0]
         fin_items = [sched.lane_item[int(q)] for q in fin_lanes]
         pre_state = sess.state
-        with self.obs.trace.span("serve.boundary", retired=len(fin_lanes),
-                                 defer=defer):
+        with self.obs.trace.span("serve.boundary", session=sess.sid,
+                                 retired=len(fin_lanes), defer=defer):
             if not defer:
                 # only the retired lanes' columns leave the device: [k, n]
-                with self.obs.trace.span("serve.gather",
+                with self.obs.trace.span("serve.gather", session=sess.sid,
                                          lanes=len(fin_lanes)):
                     rows = self._gather_rows(sess.cfg, sess.reach_fast,
-                                             pre_state, fin_lanes, fin_items)
+                                             pre_state, fin_lanes, fin_items,
+                                             sess.sid)
             stops = np.asarray(pre_state.lane_stop)[0]
             fins = []
             for i, q in enumerate(fin_lanes):
@@ -992,10 +1006,10 @@ class BFSServeEngine:
         run after the next block is already in flight, so the host-side
         unpacking overlaps the device's next sweeps."""
         pre_state, fin_lanes, fins = deferred
-        with self.obs.trace.span("serve.gather.deferred",
+        with self.obs.trace.span("serve.gather.deferred", session=sess.sid,
                                  lanes=len(fin_lanes)):
             rows = self._gather_rows(sess.cfg, sess.reach_fast,
-                                     pre_state, fin_lanes, fins)
+                                     pre_state, fin_lanes, fins, sess.sid)
             for i, item in enumerate(fins):
                 sess.complete(item, unpack_result(
                     item, rows[i], packed_reach=sess.reach_fast))
@@ -1009,7 +1023,7 @@ class BFSServeEngine:
         if self.obs.enabled:
             self.obs.metrics.histogram(
                 "serve.session_sweeps", RATIO_BUCKETS).record(sess.sweeps)
-            self.obs.trace.instant("serve.session.close",
+            self.obs.trace.instant("serve.session.close", session=sess.sid,
                                    sweeps=sess.sweeps,
                                    results=len(sess.results))
             self._export_stats()
@@ -1025,9 +1039,9 @@ class BFSServeEngine:
         while sched.n_busy:
             busy_now = sched.n_busy
             t0 = obs.clock() if obs.enabled else 0.0
-            with obs.trace.span("serve.sweep", busy=busy_now):
-                sess.state = self.profiler.timed(
-                    "sweep", sess.step_once, self.pgv, self.plan, sess.state)
+            with obs.trace.span("serve.sweep", session=sess.sid,
+                                busy=busy_now):
+                sess.state = sess.step_once(self.pgv, self.plan, sess.state)
                 sess.exclusive = False
                 sess.sweeps += 1
                 self.stats.sweeps += 1
@@ -1073,8 +1087,7 @@ class BFSServeEngine:
             blockfn = sess.block_donated if sess.exclusive else sess.block
             if obs.enabled:
                 obs.trace.instant("serve.block.dispatch", busy=sched.n_busy)
-            sess.cur = self.profiler.timed(
-                "block", blockfn, self.pgv, self.plan, sess.state, watch)
+            sess.cur = blockfn(self.pgv, self.plan, sess.state, watch)
             sess.exclusive = False
             # no speculation on a fresh dispatch: this site is only reached
             # right after a scheduler change (or at session start), where a
@@ -1089,7 +1102,7 @@ class BFSServeEngine:
             return False
         cur = sess.cur
         t0 = obs.clock() if obs.enabled else 0.0
-        with obs.trace.span("serve.block.wait",
+        with obs.trace.span("serve.block.wait", session=sess.sid,
                             busy=sess.busy_at_dispatch) as bsp:
             jax.block_until_ready(cur.lane_active)   # the lagging handle only
             active = np.asarray(cur.lane_active)[0]
@@ -1143,11 +1156,7 @@ class BFSServeEngine:
                 if obs.enabled:
                     obs.trace.instant("serve.block.dispatch",
                                       busy=sched.n_busy)
-                # speculative heads (`sess.block(...)` below) stay
-                # unprofiled: blocking on a handle chained ahead of the
-                # lagging one would defeat the very overlap it measures
-                sess.cur = self.profiler.timed(
-                    "block", blockfn, self.pgv, self.plan, sess.state, watch)
+                sess.cur = blockfn(self.pgv, self.plan, sess.state, watch)
                 sess.exclusive = False
                 sess.busy_at_dispatch = sched.n_busy
             if deferred is not None:

@@ -48,7 +48,7 @@ from dataclasses import dataclass, field, fields as _dc_fields
 
 import numpy as np
 
-from repro.obs import NULL_OBS, Observability, as_profiler, tenant_metric
+from repro.obs import NULL_OBS, Observability, tenant_metric
 
 from .engine import BFSServeEngine
 from .queries import Query, QueryKind, as_query, warm_queries
@@ -122,12 +122,6 @@ class ServeFrontend:
         frontend and every engine it builds (default: the free disabled
         plane). Per-tenant latency histograms and stats gauges land under
         ``serve.tenant.<tenant>.*`` (:func:`repro.obs.tenant_metric`).
-    profile : dispatch-latency profiling shared by every engine this
-        frontend builds (``BFSServeEngine(profile=...)`` semantics: a
-        :class:`repro.obs.DispatchProfiler`, ``True``, or a float sample
-        rate). One profiler instance spans the whole pool, so
-        ``profiler.summary()`` aggregates dispatch latencies across every
-        registered graph. Default off.
     runner_cache : the compiled-runner pool shared by every engine this
         frontend builds; pass one dict across several frontends to share
         compilations wider (benchmarks do). Default: a fresh dict.
@@ -140,12 +134,8 @@ class ServeFrontend:
     """
 
     def __init__(self, *, obs: Observability | None = None,
-                 profile=None, runner_cache: dict | None = None,
-                 **engine_defaults):
+                 runner_cache: dict | None = None, **engine_defaults):
         self.obs = obs if obs is not None else NULL_OBS
-        # one profiler across the pool: every engine built by
-        # register_graph shares it, so summary() spans the catalog
-        self.profiler = as_profiler(profile, obs=self.obs)
         self.runner_cache: dict = (runner_cache if runner_cache is not None
                                    else {})
         self._engine_defaults = dict(engine_defaults)
@@ -170,8 +160,7 @@ class ServeFrontend:
         if name in self.engines:
             raise ValueError(f"graph {name!r} already registered")
         kw = {"refill": True, "overlap": True,
-              "specialize_reachability": False,
-              "profile": self.profiler}
+              "specialize_reachability": False}
         kw.update(self._engine_defaults)
         kw.update(engine_kw)
         eng = BFSServeEngine(graph, pg=pg, obs=self.obs,

@@ -1,6 +1,5 @@
 """Device-plane telemetry: the in-jit ``tm_*`` sweep carry, its host-side
-harvest, the per-shard imbalance export, and the sampled dispatch
-profiler.
+harvest, and the per-shard imbalance export.
 
 Pinned invariants:
 
@@ -12,16 +11,8 @@ Pinned invariants:
   harvests to ``None``;
 * per-shard wire telemetry sums *exactly* to the global ``ServeStats``
   wire counters, and per-sweep frontier telemetry sums exactly to the
-  oracle's per-level vertex counts;
-* profiler sampling is deterministic (counter-based, no RNG) so sample
-  counts are pinnable, and profiling never changes answers or stats;
-* ``scripts/profile_sweep.py`` emits a schema-valid ``repro-bench/1``
-  calibration artifact that the bench gate accepts.
+  oracle's per-level vertex counts.
 """
-import importlib.util
-import json
-import os
-
 import jax
 import numpy as np
 import pytest
@@ -32,16 +23,12 @@ from repro.core.oracle import bfs_levels
 from repro.core.partition import partition_graph
 from repro.graphs.rmat import pick_sources, rmat_graph
 from repro.launch.mesh import make_test_mesh
-from repro.obs import (NULL_PROFILER, DispatchProfiler, Observability,
-                       as_profiler, harvest_telemetry, shard_metric, skew)
+from repro.obs import Observability, harvest_telemetry, shard_metric, skew
 from repro.serve import BFSServeEngine, Query, oracle_check
 
 needs4 = pytest.mark.skipif(
     len(jax.devices()) < 4,
     reason="needs >= 4 host devices (run under the multi-device CI job)")
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(scope="module")
 def graph():
@@ -92,14 +79,14 @@ def test_skew_edge_cases():
 @pytest.mark.parametrize("mode", ["batch", "refill", "overlap"])
 def test_telemetry_never_changes_schedule(graph, mode):
     """Answers and every ServeStats counter bit-identical telemetry-on
-    (with obs + profiler attached) vs a bare engine, on every driver."""
+    (with obs attached) vs a bare engine, on every driver."""
     g = graph
     kw = {"batch": {}, "refill": {"refill": True},
           "overlap": {"refill": True, "overlap": True}}[mode]
     queries = [Query(int(s)) for s in pick_sources(g, 8, seed=3)]
 
     obs = Observability()
-    eng_on = make_engine(g, telemetry=True, obs=obs, profile=True, **kw)
+    eng_on = make_engine(g, telemetry=True, obs=obs, **kw)
     eng_off = make_engine(g, **kw)
     ans_on = eng_on.submit_many(queries)
     ans_off = eng_off.submit_many(queries)
@@ -115,8 +102,6 @@ def test_telemetry_never_changes_schedule(graph, mode):
     assert eng_off.last_telemetry is None
     assert tel.p == eng_on.pg.p
     assert int(tel.shard_frontier().sum()) > 0
-    # and the profiler sampled real dispatches
-    assert eng_on.profiler.sampled == eng_on.profiler.dispatches > 0
 
 
 def test_shard_telemetry_sums_to_global_wire_counters(graph):
@@ -230,135 +215,3 @@ def test_sharded_telemetry_parity_multidevice(graph, mode):
         assert int(tel.wire_delegate.sum()) == st.wire_delegate_bytes
         assert int(tel.wire_nn.sum()) == st.wire_nn_bytes
         assert int(tel.shard_wire_bytes().sum()) == st.wire_bytes_total
-
-
-# ---------------------------------------------------------------- profiler
-def test_profiler_deterministic_sampling():
-    clock = iter(float(i) for i in range(1000))
-    prof = DispatchProfiler(sample_rate=0.5, clock=lambda: next(clock))
-    assert prof.sample_every == 2
-    for _ in range(5):
-        assert prof.timed("x", lambda: 42) == 42
-    # first dispatch sampled, then every 2nd: calls 1, 3, 5
-    assert prof.dispatches == 5 and prof.sampled == 3
-    s = prof.summary()
-    assert s["sample_rate"] == 0.5
-    assert s["dispatch_latency_s"]["x"]["count"] == 3
-    # a second name gets its own counter (its first call is sampled)
-    prof.timed("y", lambda: None)
-    assert prof.sampled == 4
-
-    full = DispatchProfiler(sample_rate=1.0, clock=lambda: next(clock))
-    for _ in range(4):
-        full.timed("z", lambda: 0)
-    assert full.sampled == full.dispatches == 4
-
-
-def test_profiler_mirrors_into_obs():
-    clock = iter(float(i) for i in range(1000))
-    obs = Observability()
-    prof = DispatchProfiler(sample_rate=1.0, obs=obs,
-                            clock=lambda: next(clock))
-    prof.timed("batch", lambda a: a + 1, 1)
-    snap = obs.metrics.snapshot()
-    assert snap["histograms"]["profile.dispatch_s.batch"]["count"] == 1
-    assert snap["counters"]["profile.samples"] == 1
-    # bind_obs only fills an empty slot
-    other = Observability()
-    prof.bind_obs(other)
-    assert prof.obs is obs
-
-
-def test_as_profiler_coercions():
-    assert as_profiler(None) is NULL_PROFILER
-    assert as_profiler(False) is NULL_PROFILER
-    assert as_profiler(NULL_PROFILER) is NULL_PROFILER
-    p = as_profiler(True)
-    assert isinstance(p, DispatchProfiler) and p.sample_every == 1
-    assert as_profiler(0.25).sample_every == 4
-    inst = DispatchProfiler(sample_rate=0.5)
-    assert as_profiler(inst) is inst
-    with pytest.raises(TypeError):
-        as_profiler("always")
-    with pytest.raises(ValueError):
-        DispatchProfiler(sample_rate=0.0)
-    with pytest.raises(ValueError):
-        DispatchProfiler(sample_rate=1.5)
-    # null profiler surface is inert
-    assert NULL_PROFILER.timed("x", lambda: 7) == 7
-    assert NULL_PROFILER.summary() == {}
-    assert NULL_PROFILER.start_trace() is False
-    with NULL_PROFILER.trace_session():
-        pass
-
-
-def test_trace_session_without_dir_is_noop():
-    prof = DispatchProfiler(sample_rate=1.0)
-    assert prof.start_trace() is False
-    with prof.trace_session():
-        pass
-    assert prof._tracing is False
-
-
-@pytest.mark.parametrize("stage", ["start", "stop"])
-def test_trace_capture_failure_propagates(tmp_path, monkeypatch, stage):
-    """A capture that was asked for (``trace_dir`` set) and fails raises out
-    of the traced window instead of leaving a clean run with no trace."""
-    def boom(*a, **k):
-        raise RuntimeError(f"profiler {stage} failed")
-
-    if stage == "start":
-        monkeypatch.setattr(jax.profiler, "start_trace", boom)
-    else:
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
-        monkeypatch.setattr(jax.profiler, "stop_trace", boom)
-    prof = DispatchProfiler(sample_rate=1.0, trace_dir=str(tmp_path))
-    with pytest.raises(RuntimeError, match=f"profiler {stage} failed"):
-        with prof.trace_session():
-            pass
-    assert prof._tracing is False
-
-
-# ------------------------------------------------- calibration artifact
-def _load_profile_sweep():
-    path = os.path.join(_REPO, "scripts", "profile_sweep.py")
-    spec = importlib.util.spec_from_file_location("profile_sweep", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_profile_sweep_calibration_artifact(tmp_path):
-    """A tiny 1-cell matrix run emits a schema-valid repro-bench/1
-    device_calibration artifact the bench gate accepts."""
-    from benchmarks.common import BENCH_SCHEMA, load_bench
-    from benchmarks.gate import gate_files
-
-    ps = _load_profile_sweep()
-    out = str(tmp_path / "CALIB_device.json")
-    payload = ps.run_matrix(
-        scale=7, requests=6, n_queries=4, max_iters=64,
-        delegates=("auto",), nn_formats=("dense",), sweep_blocks=(4,),
-        out=out)
-
-    doc = load_bench(out)
-    assert doc["schema"] == BENCH_SCHEMA
-    sec = doc["benchmarks"]["device_calibration"]
-    assert sec["graph"]["scale"] == 7 and sec["graph"]["p"] == 4
-    (key,) = sec["cells"].keys()
-    assert key == "delegate=auto,nn=dense,block=4"
-    cell = sec["cells"][key]
-    for exact in ("sweeps", "wire_delegate_bytes", "wire_nn_bytes",
-                  "nn_sparse_sweeps", "frontier_skew", "wire_skew"):
-        assert exact in cell, exact
-    assert cell["sweeps"] > 0 and cell["wire_delegate_bytes"] > 0
-    prof = cell["profile"]
-    assert prof["sampled"] > 0
-    assert "block" in prof["dispatch_latency_s"]
-    assert payload["cells"][key]["sweeps"] == cell["sweeps"]
-
-    # the gate parses + self-diffs the artifact clean
-    rep = gate_files([out], [out])
-    assert rep["status"] == "pass"
-    assert all(f["status"] == "ok"
-               for r in rep["reports"] for f in r["findings"])
