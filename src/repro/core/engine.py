@@ -45,19 +45,26 @@ class ExchangePlan:
     ship slot bitmasks, receivers decode locally)."""
 
     perm: Any        # [p, E_nn_max] int32: edge order sorted by (owner, local)
-    seg_ids: Any     # [p, E_nn_max] int32: run index of unique (owner, local)
+    seg_ids: Any     # [p, E_nn_max] int32: run index of unique (owner, local);
+    #                  ascending, so each slot's edges are one contiguous run
     seg_owner: Any   # [p, cap_total] int32: owner partition per unique dst (p = invalid)
     seg_pos: Any     # [p, cap_total] int32: slot within the owner's bin
     seg_local: Any   # [p, cap_total] int32: local id at the destination
     recv_local: Any = None  # [p, p, cap_peer] int32: (peer, slot) -> my local id
+    src_rows: Any = None  # [p, E_nn_max] int32: nn.rowids[perm], the source
+    #                       row of each slot-sorted edge (padding: n_rows)
+    seg_end: Any = None   # [p, cap_total] int32: permuted index of each run's
+    #                       last edge; E_nn_max for slots past the unique count
     cap_peer: int = 0   # per-peer slot capacity (multiple of 32)
     cap_total: int = 0  # unique (owner, local) capacity per partition
+    max_run: int = 1    # longest run (edges of one slot) over all partitions
 
 
 jax.tree_util.register_dataclass(
     ExchangePlan,
-    data_fields=("perm", "seg_ids", "seg_owner", "seg_pos", "seg_local", "recv_local"),
-    meta_fields=("cap_peer", "cap_total"),
+    data_fields=("perm", "seg_ids", "seg_owner", "seg_pos", "seg_local",
+                 "recv_local", "src_rows", "seg_end"),
+    meta_fields=("cap_peer", "cap_total", "max_run"),
 )
 
 
@@ -74,16 +81,21 @@ jax.tree_util.register_dataclass(EdgeWeights, data_fields=("nn", "nd", "dn", "dd
 
 def build_exchange_plan(pg: PartitionedGraph) -> ExchangePlan:
     """Host-side: sort each partition's nn edges by (owner, local dst) and
-    record the unique-destination segments and their slots."""
+    record the unique-destination segments and their slots, each
+    segment's last sorted edge, and the sorted edges' source rows."""
     p = pg.p
     e_max = pg.nn.e_max
     cols = np.asarray(pg.nn.cols)         # local dst id at the owner
     owners = np.asarray(pg.nn_owner)      # owner partition per nn edge
+    rowids = np.asarray(pg.nn.rowids)
     m = np.asarray(pg.nn.m)
 
     perms = np.tile(np.arange(e_max, dtype=np.int32), (p, 1))
     seg_ids = np.zeros((p, e_max), dtype=np.int32)
+    src_rows = np.full((p, e_max), pg.nn.n_rows, dtype=np.int32)
     seg_data = []
+    run_ends = []
+    max_run = 1
     for k in range(p):
         mk = int(m[k])
         owner = owners[k, :mk]
@@ -102,10 +114,16 @@ def build_exchange_plan(pg: PartitionedGraph) -> ExchangePlan:
             sel = u_owner == peer
             u_pos[sel] = np.arange(sel.sum(), dtype=np.int32)
         perms[k, :mk] = order
+        src_rows[k, :mk] = rowids[k, :mk][order]
         # padding edges get a dedicated trash segment
         seg_ids[k, :mk] = sid
         seg_ids[k, mk:] = (sid[-1] + 1) if mk else 0
         seg_data.append((u_owner, u_pos, u_local))
+        starts = np.flatnonzero(new_seg)
+        ends = np.append(starts[1:], mk) - 1
+        run_ends.append(ends)
+        if mk:
+            max_run = max(max_run, int((ends - starts).max()) + 1)
 
     cap_peer = 1
     for u_owner, _, _ in seg_data:
@@ -117,16 +135,20 @@ def build_exchange_plan(pg: PartitionedGraph) -> ExchangePlan:
     seg_pos = np.zeros((p, cap_total), dtype=np.int32)
     seg_local = np.zeros((p, cap_total), dtype=np.int32)
     recv_local = np.full((p, p, cap_peer), -1, dtype=np.int32)
+    # slots past a partition's unique count point one past the last edge
+    seg_end = np.full((p, cap_total), e_max, dtype=np.int32)
     for k, (uo, up, ul) in enumerate(seg_data):
         seg_owner[k, : uo.size] = uo
         seg_pos[k, : up.size] = up
         seg_local[k, : ul.size] = ul
+        seg_end[k, : uo.size] = run_ends[k]
         # receiver-side inverse: owner j's table gets (sender k, slot) -> local
         recv_local[uo, k, up] = ul
     return ExchangePlan(
         perm=perms, seg_ids=seg_ids, seg_owner=seg_owner, seg_pos=seg_pos,
         seg_local=seg_local, recv_local=recv_local,
-        cap_peer=cap_peer, cap_total=cap_total,
+        src_rows=src_rows, seg_end=seg_end,
+        cap_peer=cap_peer, cap_total=cap_total, max_run=max_run,
     )
 
 

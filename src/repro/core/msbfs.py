@@ -12,7 +12,9 @@ Every traversal sweep, every delegate all-reduce, and every nn all_to_all
 is then amortized over the whole batch:
 
 * **push** is a scatter-OR of lane words along edges (one gather + one
-  scatter for all W queries);
+  scatter for all W queries); along nn edges, whose destinations the
+  exchange plan sorts into contiguous per-slot runs, it is a scatter-free
+  segmented OR of packed uint32 lane words over those runs;
 * **pull** is the chunked parent scan with *word-OR early exit*: a row
   drops out of the scan as soon as the accumulated parent word covers all
   of its still-unvisited lanes;
@@ -35,8 +37,9 @@ is then amortized over the whole batch:
   pull while a late straggler query in the same batch still pushes.
 
 On device the lane axis is kept as trailing bools (vectorized compute);
-packing to uint32 happens exactly at the two communication boundaries, so
-the wire format matches the paper's Section V accounting.
+packing to uint32 happens at the two communication boundaries, so the wire
+format matches the paper's Section V accounting, and in the nn slot scan,
+whose per-edge words it shrinks from W bytes to 4 per 32 lanes.
 
 **Typed queries.** Each lane additionally carries query parameters so the
 serving layer can compile richer query shapes onto the same substrate
@@ -121,12 +124,14 @@ class MSBFSConfig:
     # capped id+word pairs / the per-sweep frontier-adaptive switch). The
     # default reproduces the seed behavior bit-for-bit.
     comm: comm.CommConfig = comm.CommConfig()
-    # Out-of-core sweep mode (ROADMAP item 2): > 0 streams the push
-    # scatters and the nn slot-accumulate through a ``lax.scan`` over
+    # Out-of-core sweep mode (ROADMAP item 2): > 0 streams the bool push
+    # scatters and the payload plane through a ``lax.scan`` over
     # fixed-size edge blocks and row-blocks the pull scan
     # (``edge_chunk // pull_chunk`` rows per block), so peak sweep memory
     # is O(edge_chunk * W) instead of O(E_max * W) -- a partition whose
     # decoded [E, W] working set exceeds device memory still traverses.
+    # The nn slot words are not chunked: their segmented OR holds one
+    # packed uint32 word per 32 lanes per edge (:func:`_nn_slots_multi`).
     # Bit-identical to the monolithic path by construction: scatter-OR is
     # order-independent, each pull row's early exit and work count depend
     # only on that row, and all counters are exact int32 sums -- chunking
@@ -477,45 +482,66 @@ def _push_multi(csr: CSR, frontier_rows: jnp.ndarray, n_dst: int,
     return out
 
 
-def _nn_slots_multi(csr: CSR, frontier_rows: jnp.ndarray, plan,
-                    edge_chunk: int = 0):
+# XLA's TPU gather costs about 8.6 ns an index; issued as a loop over
+# blocks of this many indices it costs about 7.4 ns (TPU v5e, 33.5M indices)
+GATHER_BLOCK = 1 << 18
+
+
+def _gather_blocks(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``table[idx]`` for 1-D ``idx``, issued in blocks of ``GATHER_BLOCK``
+    indices (the tail block padded with index 0, its extra reads dropped)."""
+    e = idx.shape[0]
+    if e <= GATHER_BLOCK:
+        return table[idx]
+    nb = -(-e // GATHER_BLOCK)
+    blocks = jnp.pad(idx, (0, nb * GATHER_BLOCK - e)).reshape(nb, GATHER_BLOCK)
+    return lax.map(lambda b: table[b], blocks).reshape(-1)[:e]
+
+
+def nn_scan_steps(max_run: int) -> int:
+    """Doubling steps of :func:`_nn_slots_multi`'s segmented OR for runs of
+    at most ``max_run`` edges: ``ceil(log2(max_run))``."""
+    return (int(max_run) - 1).bit_length()
+
+
+def _nn_slots_multi(frontier_rows: jnp.ndarray, plan):
     """Sender-side unique-slot lane words for the nn exchange.
 
     Returns ``(sa [cap_total, W] bool, act_sum int32)`` where ``act_sum``
     is the total active (edge, lane) count -- exactly
-    ``jnp.sum(_push_active_multi(...))``, the nn term of ``work_fwd``
-    (``plan.perm`` is a permutation, so summing in permuted order is
-    identical). ``edge_chunk > 0`` streams the plan-permuted edge order in
-    fixed-size blocks, never materializing [E, W]; padding blocks gather
-    the all-False extended-frontier row and land in the dump slot
-    ``cap_total`` that the final slice drops.
+    ``jnp.sum(_push_active_multi(nn, frontier_rows))``, the nn term of
+    ``work_fwd``.
+
+    No scatter: the plan sorts each partition's nn edges by slot, so a
+    slot's edges are one contiguous run of ``plan.seg_ids`` and its lane
+    word is a segmented OR over that run. The frontier is packed to
+    ``n_words(W)`` uint32 words a row; each slot-sorted edge gathers its
+    source row's words (``plan.src_rows``; padding edges read the appended
+    zero row), and ``act_sum`` is their popcount. A Hillis-Steele
+    inclusive scan of ``ceil(log2(plan.max_run))`` doubling steps ORs into
+    each edge the word of the edge ``k`` back when both lie in one run,
+    which is exact because runs are contiguous; each slot then reads its
+    run's last edge (``plan.seg_end``; slots past the partition's unique
+    count read the appended zero word). The words stay ``nw`` separate
+    ``[E]`` vectors, 4 bytes an edge each: not chunked by ``edge_chunk``.
     """
     w = frontier_rows.shape[-1]
-    f_ext = jnp.concatenate(
-        [frontier_rows, jnp.zeros((1, w), frontier_rows.dtype)])
-    if edge_chunk <= 0 or edge_chunk >= csr.e_max:
-        act = f_ext[csr.rowids]
-        sa = jnp.zeros((plan.cap_total + 1, w), jnp.bool_).at[
-            plan.seg_ids].max(act[plan.perm])[: plan.cap_total]
-        return sa, jnp.sum(act.astype(jnp.int32))
-    nblk = -(-csr.e_max // edge_chunk)
-    pad = nblk * edge_chunk - csr.e_max
-    rid = jnp.pad(csr.rowids[plan.perm], (0, pad),
-                  constant_values=csr.n_rows).reshape(nblk, edge_chunk)
-    seg = jnp.pad(plan.seg_ids, (0, pad),
-                  constant_values=plan.cap_total).reshape(nblk, edge_chunk)
-
-    def body(carry, blk):
-        sa, tot = carry
-        r, s = blk
-        act = f_ext[r]
-        return (sa.at[s].max(act), tot + jnp.sum(act.astype(jnp.int32))), None
-
-    (sa, tot), _ = lax.scan(
-        body,
-        (jnp.zeros((plan.cap_total + 1, w), jnp.bool_), jnp.int32(0)),
-        (rid, seg))
-    return sa[: plan.cap_total], tot
+    words = pack_lanes(frontier_rows)                          # [nl, nw]
+    words = jnp.concatenate(
+        [words, jnp.zeros((1, words.shape[-1]), jnp.uint32)])
+    seg = plan.seg_ids
+    cols, act_sum = [], jnp.int32(0)
+    for i in range(words.shape[-1]):
+        x = _gather_blocks(words[:, i], plan.src_rows)         # [E] uint32
+        act_sum += jnp.sum(lax.population_count(x).astype(jnp.int32))
+        for k in (1 << j for j in range(nn_scan_steps(plan.max_run))):
+            same = jnp.concatenate(
+                [jnp.zeros((k,), bool), seg[k:] == seg[:-k]])
+            prev = jnp.concatenate([jnp.zeros((k,), jnp.uint32), x[:-k]])
+            x = x | jnp.where(same, prev, jnp.uint32(0))
+        x = jnp.concatenate([x, jnp.zeros((1,), jnp.uint32)])
+        cols.append(_gather_blocks(x, plan.seg_end))           # [cap_total]
+    return unpack_lanes(jnp.stack(cols, axis=-1), w), act_sum
 
 
 def _push_payload(csr: CSR, front: jnp.ndarray, pay_rows: jnp.ndarray,
@@ -806,9 +832,9 @@ def msbfs_step(
     # Lanes in forward mode push their frontier word; lanes in backward mode
     # pull into their unvisited word. Results are disjoint per lane, so the
     # per-lane merge is a plain OR.
-    # edge_chunk > 0: stream pushes / the nn accumulate over edge blocks
-    # and row-block the pulls at ~edge_chunk edge slots per step (see
-    # MSBFSConfig.edge_chunk -- bit-identical to monolithic, memory only)
+    # edge_chunk > 0: stream the pushes over edge blocks and row-block the
+    # pulls at ~edge_chunk edge slots per step (see MSBFSConfig.edge_chunk
+    # -- bit-identical to monolithic, memory only)
     ec = cfg.edge_chunk
     rb = max(1, ec // max(cfg.pull_chunk, 1)) if ec > 0 else 0
 
@@ -840,7 +866,7 @@ def msbfs_step(
     # format (dense lane words / sparse id+word pairs / per-sweep adaptive
     # switch / compressed codec) selected by cfg.comm.nn in the comm layer
     with jax.named_scope("msbfs.nn.slots"):
-        sa, act_nn_sum = _nn_slots_multi(pgv.nn, frontier_n, plan, ec)
+        sa, act_nn_sum = _nn_slots_multi(frontier_n, plan)
     with jax.named_scope("msbfs.nn.exchange"):
         rows = jnp.minimum(plan.seg_owner, p - 1)
         ok = plan.seg_owner < p

@@ -72,7 +72,11 @@ def synth_partitioned_graph(
         seg_pos=arr((cap_total,), np.int32),
         seg_local=arr((cap_total,), np.int32),
         recv_local=arr((p, cap_peer), np.int32),
-        cap_peer=cap_peer, cap_total=cap_total,
+        src_rows=arr((e_nn,), np.int32),
+        seg_end=arr((cap_total,), np.int32),
+        # a normal destination of an edge-doubled graph has at most th
+        # in-edges: 6 doubling steps at th = 64
+        cap_peer=cap_peer, cap_total=cap_total, max_run=pg.th,
     )
     weights = EdgeWeights(
         nn=arr((e_nn,), np.float32), nd=arr((e_nd,), np.float32),

@@ -432,6 +432,11 @@ class BFSServeEngine:
                 refill=self.refill, overlap=self.overlap,
                 sweep_block=self.sweep_block,
                 comm=self.cfg.comm.as_dict())
+            # the nn slot scan's static depth: its longest slot run and
+            # the segmented OR's doubling steps (core/msbfs.py)
+            self.obs.metrics.gauge("msbfs.nn.max_run").set(self.plan.max_run)
+            self.obs.metrics.gauge("msbfs.nn.scan_steps").set(
+                M.nn_scan_steps(self.plan.max_run))
         self._layout = PartitionLayout(pg.n, pg.p_rank, pg.p_gpu)
         # exactly the pg.d real delegate ids -- *empty* on a delegate-free
         # graph (the replicated arrays pad to max(d, 1) for static shapes,

@@ -243,6 +243,15 @@ def test_msbfs_scope_in_lowered_block(lowered, scope):
                              loc) for loc in hits)
 
 
+def test_nn_slots_scope_holds_no_scatter_or_sort(lowered):
+    """The nn slot words are a segmented OR over the plan's slot-sorted
+    runs: ``msbfs.nn.slots`` names the word gathers, the popcount and the
+    ORs, and no scatter or sort."""
+    prims = set(re.findall(r"[/(]msbfs\.nn\.slots\)?/([\w-]+)", lowered))
+    assert {"gather", "population_count", "or"} <= prims, prims
+    assert not [op for op in prims if "scatter" in op or "sort" in op]
+
+
 def test_block_module_name_keeps_scoped_builds_apart(lowered):
     """The persistent compilation cache's key leaves op metadata out, so
     only the module's name keeps an executable cached from a build of the
