@@ -17,7 +17,10 @@ operations. This module names them after the program instead:
   its path. The paths come from xprof's ``op_profile``, per compiled
   program (the trace's ``XLA Modules`` line says which program an
   operation ran in). A fusion whose root names no scope goes to the scope
-  most of its fused operations name. xprof's own per-path times
+  most of its fused operations name. ``op_profile`` lists at most 100
+  operations under a node and groups identical ones under a node of its
+  own; xprof's ``hlo_stats`` table lists every operation with its own
+  path, and names those. xprof's own per-path times
   (``framework_op_stats``) are not used: they count a loop's time again
   beside the operations of its body.
 
@@ -48,7 +51,10 @@ GATHERS = ("serve.gather", "serve.gather.deferred")
 MODULES_LINE = "XLA Modules"
 SCOPE = re.compile(r"msbfs(?:\.[a-z]+)+")
 NN = ("msbfs.nn.slots", "msbfs.nn.exchange")
+DELEGATE = ("msbfs.dd", "msbfs.nd", "msbfs.dn", "msbfs.delegate.combine")
 NONE = "none"
+DUPLICATES = " and its duplicate(s)"
+PROGRAM_ID = re.compile(r"\((\d+)\)$")
 
 
 def read(path: str):
@@ -208,36 +214,63 @@ def node_scope(node: dict) -> str:
 def profile_scopes(profile: dict) -> dict:
     """``{program: {op_name: scope}}`` from a parsed ``op_profile``: a
     program's executed operations are the first nodes with ``xla``
-    details under it (the nodes below those are fused into them)."""
+    details under it (the nodes below those are fused into them), except
+    a node that groups identical operations (``<op> and its
+    duplicate(s)``), whose children are the operations."""
     out: dict = {}
     for prog in profile.get("byProgram", {}).get("children", []):
         ops = out.setdefault(prog["name"], {})
         stack = list(prog.get("children", []))
         while stack:
             n = stack.pop()
-            if n.get("xla"):
+            if n.get("xla") and not n["name"].endswith(DUPLICATES):
                 ops[n["name"]] = node_scope(n)
             else:
                 stack.extend(n.get("children", []))
     return out
 
 
+def stats_scopes(table: dict, programs) -> dict:
+    """``{program: {op_name: scope}}`` from a parsed ``hlo_stats`` table:
+    every operation of every program, by its own path. ``programs`` are
+    the programs' names (``jit_f(<id>)``); the table gives only the id."""
+    name_of = {m.group(1): p for p in programs
+               if (m := PROGRAM_ID.search(p))}
+    cols = [c["id"] for c in table.get("cols", [])]
+    out: dict = {}
+    for row in table.get("rows", []):
+        r = dict(zip(cols, ((c or {}).get("v") for c in row["c"])))
+        prog = name_of.get(str(r.get("program_id")))
+        if prog is not None:
+            out.setdefault(prog, {})[r["hlo_op_name"]] = scope_of(
+                r.get("tf_op_name") or "")
+    return out
+
+
 def op_scopes(path: str) -> dict | None:
-    """:func:`profile_scopes` of xprof's ``op_profile`` of a copy of the
-    trace; None where xprof is missing or fails."""
+    """``{program: {op_name: scope}}`` of a copy of the trace: xprof's
+    ``hlo_stats`` for every operation, :func:`profile_scopes` of its
+    ``op_profile`` where that lists the operation (it also names a fusion
+    whose root names no scope); None where xprof is missing or fails."""
     try:
         from xprof.convert import raw_to_tool_data
     except ImportError:
         return None
+    tools = {}
     with tempfile.TemporaryDirectory() as tmp:
         copy = shutil.copy(path, tmp)
-        try:
-            data, _ = raw_to_tool_data.xspace_to_tool_data(
-                [copy], "op_profile", {})
-        except Exception as exc:  # noqa: BLE001 -- a reader never raises
-            print(f"attribution: xprof failed: {exc!r}", file=sys.stderr)
-            return None
-    return profile_scopes(json.loads(data))
+        for tool in ("op_profile", "hlo_stats"):
+            try:
+                data, _ = raw_to_tool_data.xspace_to_tool_data(
+                    [copy], tool, {})
+                tools[tool] = json.loads(data)
+            except Exception as exc:  # noqa: BLE001 -- a reader never raises
+                print(f"attribution: xprof {tool} failed: {exc!r}",
+                      file=sys.stderr)
+                return None
+    profile = profile_scopes(tools["op_profile"])
+    stats = stats_scopes(tools["hlo_stats"], profile)
+    return {p: {**stats.get(p, {}), **ops} for p, ops in profile.items()}
 
 
 def device_split(window, devices, scopes) -> dict | None:

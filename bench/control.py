@@ -47,11 +47,13 @@ def sampled_requests(cell: dict, n: int, src, seed: int, seconds: float,
     traffic = cell["traffic"]
     if traffic["driver"] == "open_loop":
         count = max(int(round(traffic["rate_qps"] * seconds)), 1)
-        plain = run.point_requests(traffic, n, src, seed, count, block=1)
+        plain = run.point_requests(cell["config"], traffic, n, src, seed,
+                                   count, block=1)
         return [plain[i] for i in gen.sample(count, traffic["check_requests"],
                                              seed)]
     checks = run.KeySetChecks(traffic, seed)
-    for i, keys in enumerate(run.key_sets(traffic, n, src, seed)[:sets]):
+    for i, keys in enumerate(run.key_sets(cell["config"], traffic, n, src,
+                                          seed)[:sets]):
         checks.add(i, keys, [None] * len(keys))
     return checks.all()
 

@@ -13,11 +13,16 @@ edge doubling, self loops removed.
 * ``urand``: GAP "urand" (Beamer, Asanovic, Patterson, arXiv:1508.03619):
   ``edge_factor * 2**scale`` edges with uniform endpoints.
 
-The seed draws the vertex labels; the edge multiset comes from the
+The seed draws the vertex labels, unless the configuration fixes them
+with a ``label_seed`` of its own; the edge multiset comes from the
 configuration's fixed ``base_seed`` (see :func:`make_graph`).
 
 Search keys follow the Graph500 rule: drawn uniformly, without
-replacement, from the vertices of degree > 0.
+replacement, from the vertices of degree > 0. A traffic file with a
+``base_seed`` fixes them once per graph, as the Graph500 reference code
+draws its keys from one seed: the same base vertices under every seed's
+labels (:func:`fixed_search_keys`), each set's lanes in an order drawn
+from the seed (:func:`lane_order`).
 
 Traffic schedules keep the work the same for every seed: the request
 descriptors, their inter-arrival gaps and the base vertex each popularity
@@ -36,7 +41,7 @@ SEED_MOD = 1 << 64
 
 # stream tags: each use of the seed draws from its own independent stream
 # (a block or set index adds multiples of 16)
-_KEYS, _RANKS, _SAMPLE, _ARRIVALS = 2, 3, 5, 6
+_KEYS, _RANKS, _SAMPLE, _ARRIVALS, _FIXED_KEYS, _LANES = 2, 3, 5, 6, 7, 8
 
 
 def rng(seed: int, tag: int) -> np.random.Generator:
@@ -103,22 +108,31 @@ def labels(n: int, seed: int) -> np.ndarray:
     return np.asarray(_labels_jit()(device_key(seed), n), np.int64)
 
 
+def label_seed(cfg: dict, seed: int) -> int:
+    """The seed of the vertex labels: the configuration's own
+    ``label_seed`` where it fixes them once per graph, else the run's."""
+    return cfg.get("label_seed", seed)
+
+
 def make_graph(cfg: dict, seed: int):
     """The configuration's graph for ``seed``: ``(n, src, dst)``, int64.
 
     The edge multiset is drawn once from the configuration's ``base_seed``
     (RMAT or uniform endpoints, one jitted call on the default device);
-    ``seed`` draws the vertex labels, a permutation of all ``2**scale``
-    ids, as Graph500 asks. Every seed thus gets the same degree sequence
-    and subgraph sizes under another labelling, and so the same compiled
-    shapes."""
+    :func:`label_seed` draws the vertex labels, a permutation of all
+    ``2**scale`` ids, as Graph500 asks. Every seed thus gets the same
+    degree sequence and subgraph sizes, and so the same compiled shapes.
+    Where the labels come from the run's seed, each seed gets another
+    labelling; a configuration whose work hangs on the labelling (the
+    order of a hub's parents decides where a pull stops) fixes it."""
     if cfg["generator"] not in ("rmat", "urand"):
         raise ValueError(f"unknown generator {cfg['generator']!r}")
     n = 1 << cfg["scale"]
     m = n * cfg["edge_factor"]
     abcd = tuple(cfg.get("rmat_abcd", (0.25, 0.25, 0.25, 0.25)))
     src, dst = _edges_jit()(cfg["generator"], cfg["scale"], m, abcd,
-                      device_key(cfg["base_seed"]), device_key(seed))
+                      device_key(cfg["base_seed"]),
+                      device_key(label_seed(cfg, seed)))
     return undirected(n, np.asarray(src, np.int64), np.asarray(dst, np.int64))
 
 
@@ -184,17 +198,38 @@ def point_requests(traffic: dict, count: int, support: int, block: int):
     return kinds, src, tgt
 
 
-def popularity_order(n: int, src: np.ndarray, seed: int,
-                     base_seed: int) -> np.ndarray:
-    """Vertex named by each popularity rank. The ranking is drawn once,
-    from the traffic file's ``base_seed``, over the non-isolated vertices of
-    the configuration's base graph; ``seed`` only names them by its labels.
-    So every seed sends its requests to the same base vertices, and the
-    requests do the same work."""
+def base_order(n: int, src: np.ndarray, seed: int, base_seed: int,
+               tag: int) -> np.ndarray:
+    """The non-isolated vertices of the configuration's base graph in an
+    order drawn once, from ``(base_seed, tag)``, each named by ``seed``'s
+    labels. Every seed gets the same base vertices in the same order, so
+    what is drawn from it does the same work."""
     perm = labels(n, seed)
     base = np.nonzero(np.bincount(src, minlength=n)[perm] > 0)[0]
-    order = np.random.default_rng([int(base_seed), _RANKS])
+    order = np.random.default_rng([int(base_seed), tag])
     return perm[base[order.permutation(base.size)]]
+
+
+def popularity_order(n: int, src: np.ndarray, seed: int,
+                     base_seed: int) -> np.ndarray:
+    """Vertex named by each popularity rank, drawn from the traffic file's
+    ``base_seed`` (:func:`base_order`): every seed sends its requests to
+    the same base vertices."""
+    return base_order(n, src, seed, base_seed, _RANKS)
+
+
+def fixed_search_keys(n: int, src: np.ndarray, count: int, seed: int,
+                      base_seed: int) -> np.ndarray:
+    """Graph500 search keys fixed once per graph: the first ``count`` of
+    :func:`base_order` drawn from the traffic file's ``base_seed``, so
+    every seed searches from the same base vertices under its labels."""
+    return base_order(n, src, seed, base_seed, _FIXED_KEYS)[:count]
+
+
+def lane_order(keys: np.ndarray, seed: int, index: int) -> np.ndarray:
+    """Key set ``index`` in an order drawn from ``seed``: the same keys,
+    each on another lane, so the set does the same work."""
+    return keys[rng(seed, _LANES + 16 * index).permutation(keys.size)]
 
 
 def arrival_times(count: int, seconds: float, seed: int) -> np.ndarray:

@@ -153,13 +153,14 @@ def program():
     """The program's serving API (imported only once the chip is known)."""
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro import compile_cache
-    from repro.core.msbfs import MSBFSConfig
+    from repro.core.msbfs import MSBFSConfig, nn_scan_steps
     from repro.core.partition import partition_graph
     from repro.core.types import COOGraph
     from repro.obs import Observability
     from repro.serve import BFSServeEngine, Query, QueryKind, ServeFrontend
 
     return {"compile_cache": compile_cache, "MSBFSConfig": MSBFSConfig,
+            "nn_scan_steps": nn_scan_steps,
             "partition_graph": partition_graph, "COOGraph": COOGraph,
             "Observability": Observability, "BFSServeEngine": BFSServeEngine,
             "Query": Query, "QueryKind": QueryKind,
@@ -172,6 +173,14 @@ def engine_kwargs(P, cfg: dict) -> dict:
             "refill": e["refill"], "overlap": e["overlap"],
             "sweep_block": e["sweep_block"], "edge_chunk": e["edge_chunk"],
             "cache_capacity": e["cache_capacity"]}
+
+
+def log_plan(P, eng) -> None:
+    """The nn slot scan's static shape: the plan's longest slot run and the
+    segmented OR's doubling steps (set-up log only, no metric)."""
+    run_len = eng.plan.max_run
+    log(f"plan: msbfs.nn.max_run={run_len} "
+        f"msbfs.nn.scan_steps={P['nn_scan_steps'](run_len)}")
 
 
 def partition(P, cfg: dict, n: int, src, dst):
@@ -242,11 +251,12 @@ def stats_delta(a: dict, b: dict) -> dict:
 
 
 # -- drivers -----------------------------------------------------------------
-def point_requests(traffic: dict, n: int, src, seed: int, count: int,
-                   block: int) -> list:
+def point_requests(cfg: dict, traffic: dict, n: int, src, seed: int,
+                   count: int, block: int) -> list:
     """Block ``block`` of the open-loop stream as plain descriptors."""
     kinds, srank, trank = gen.point_requests(traffic, count, n, block)
-    names = gen.popularity_order(n, src, seed, traffic["base_seed"])
+    names = gen.popularity_order(n, src, gen.label_seed(cfg, seed),
+                                 traffic["base_seed"])
     out = []
     for k, s, t in zip(kinds, srank, trank):
         kind = traffic["kinds"][k]
@@ -258,11 +268,21 @@ def point_requests(traffic: dict, n: int, src, seed: int, count: int,
     return out
 
 
-def key_sets(traffic: dict, n: int, src, seed: int) -> list:
-    """The run's key sets: distinct search keys, ``keys_per_set`` a set."""
+def key_sets(cfg: dict, traffic: dict, n: int, src, seed: int) -> list:
+    """The run's key sets: distinct search keys, ``keys_per_set`` a set.
+    With a ``base_seed`` the traffic fixes its keys once per graph (the
+    same base vertices for every seed) and the seed orders each set's
+    lanes; without one the seed draws them."""
     size = traffic["keys_per_set"]
-    pool = gen.search_keys(n, src, size * traffic["max_sets"], seed)
-    return [pool[i:i + size] for i in range(0, pool.size - size + 1, size)]
+    count = size * traffic["max_sets"]
+    if "base_seed" not in traffic:
+        pool = gen.search_keys(n, src, count, seed)
+        return [pool[i:i + size]
+                for i in range(0, pool.size - size + 1, size)]
+    pool = gen.fixed_search_keys(n, src, count, gen.label_seed(cfg, seed),
+                                 traffic["base_seed"])
+    return [gen.lane_order(pool[i:i + size], seed, i // size)
+            for i in range(0, pool.size - size + 1, size)]
 
 
 def make_query(P, d: dict):
@@ -312,12 +332,13 @@ def run_open_loop(P, run: dict, pg, n: int, src) -> dict:
     obs = P["Observability"](trace_capacity=1 << 21) if traced else None
     fe = P["ServeFrontend"](obs=obs)
     eng = fe.register_graph("graph", pg=pg, **engine_kwargs(P, cfg))
+    log_plan(P, eng)
     sess = fe.open_session("bench", "graph", slo="latency")
     rate = run.get("rate") or traffic["rate_qps"]
     count = max(int(round(rate * seconds)), 1)
     prefix = [make_query(P, d) for d in point_requests(
-        traffic, n, src, seed, traffic["warmup_requests"], block=0)]
-    plain = point_requests(traffic, n, src, seed, count, block=1)
+        cfg, traffic, n, src, seed, traffic["warmup_requests"], block=0)]
+    plain = point_requests(cfg, traffic, n, src, seed, count, block=1)
     stream = [make_query(P, d) for d in plain]
     due = gen.arrival_times(count, seconds, traffic["base_seed"])
     t = clock()
@@ -419,8 +440,9 @@ def run_key_sets(P, run: dict, pg, n: int, src) -> dict:
     seconds, traced = run["seconds"], run["trace"]
     obs = P["Observability"](trace_capacity=1 << 21) if traced else None
     eng = P["BFSServeEngine"](pg=pg, obs=obs, **engine_kwargs(P, cfg))
+    log_plan(P, eng)
     size = traffic["keys_per_set"]
-    sets = key_sets(traffic, n, src, seed)
+    sets = key_sets(cfg, traffic, n, src, seed)
     query = lambda k: make_query(P, levels_request(k))  # noqa: E731
     t = clock()
     eng.warmup(queries=[query(k) for k in sets[0]])

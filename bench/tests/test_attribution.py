@@ -19,7 +19,8 @@ TESTDATA = os.path.join(run.HERE, "testdata")
 PLAIN = os.path.join(TESTDATA, "keysets-scale12.xplane.pb")
 SCOPED = os.path.join(TESTDATA, "keysets-graph500-scale12-scoped.xplane.pb")
 S = 1_000_000_000     # ns
-READERS = ("gather_idle_share.bfs", "nn_ms_per_sweep.bfs")
+READERS = ("gather_idle_share.bfs", "nn_ms_per_sweep.bfs",
+           "delegate_ms_per_sweep.bfs")
 
 
 def snapshot(path):
@@ -69,7 +70,9 @@ def test_scoped_trace_names_idle_and_busy_time(untouched):
     assert sum(dev.values()) <= red["busy_s"] + 1e-9
     assert sum(dev.values()) == pytest.approx(red["busy_s"], rel=0.02)
     share = at.shares(att)
-    assert share["busy_under_msbfs"] > 0.9 and share["idle_under_serve"] > 0.9
+    # every operation is named: op_profile's groups of duplicates and the
+    # operations past its 100 a node come from hlo_stats
+    assert share["busy_under_msbfs"] > 0.99 and share["idle_under_serve"] > 0.9
     # Kronecker degrees: the delegate subgraphs take most of the sweep
     assert dev["msbfs.nd"] > dev["msbfs.nn.slots"] > 0
     assert 0 < host["gather_idle_s"] < host["idle_s"]
@@ -81,6 +84,19 @@ def test_scoped_trace_names_idle_and_busy_time(untouched):
                                          + dev["msbfs.nn.exchange"]) / sweeps)
     assert run.reader("gather_idle_share.bfs")(ctx) == pytest.approx(
         100.0 * host["gather_idle_s"] / host["window_s"])
+
+
+def test_delegate_scopes_hold_most_of_a_kronecker_sweep(untouched):
+    dev = at.attribute(SCOPED)["device"]
+    sweeps = 31
+    ctx = {"trace": SCOPED, "stats": {"sweeps": sweeps}}
+    delegate = run.reader("delegate_ms_per_sweep.bfs")(ctx)
+    want = sum(dev.get(k, 0.0) for k in at.DELEGATE)
+    assert delegate == pytest.approx(1000.0 * want / sweeps)
+    # the dd, nd and dn subgraphs: about 94% of the recorded busy time
+    share = want / tr.reduce_file(SCOPED)["busy_s"]
+    assert 0.92 < share < 0.95
+    assert delegate > run.reader("nn_ms_per_sweep.bfs")(ctx) > 0
 
 
 def test_idle_gaps_go_to_the_innermost_span():
@@ -160,7 +176,11 @@ def test_profile_scopes_take_the_root_else_the_fused_majority():
                    op("fusion.4", ".../vmap(msbfs.dd)/gather:",
                       op("gather.5", ".../vmap(msbfs.dd)/gather:")),
                    op("scatter.6", ".../vmap(msbfs.update)/select:")),
-                op("copy.7", ":")]}]},
+                op("copy.7", ":")]},
+            {"name": "loop fusion", "children": [
+                op("fusion.8 and its duplicate(s)", ".../vmap(msbfs.nd)/or:",
+                   op("fusion.8", ".../vmap(msbfs.nd)/or:"),
+                   op("fusion.9", ".../vmap(msbfs.dd)/or:"))]}]},
         {"name": "jit__reseed_lanes_impl(9)", "children": [
             {"name": "scatter", "children": [
                 op("scatter.1", "jit(_reseed_lanes_impl)/msbfs.reseed/"
@@ -168,7 +188,29 @@ def test_profile_scopes_take_the_root_else_the_fused_majority():
     assert at.profile_scopes(profile) == {
         "jit_msbfs_block(7)": {"fusion.1": "msbfs.nn.slots",
                                "fusion.2": "msbfs.dd",
-                               "copy.7": at.NONE},
+                               "copy.7": at.NONE,
+                               "fusion.8": "msbfs.nd",
+                               "fusion.9": "msbfs.dd"},
+        "jit__reseed_lanes_impl(9)": {"scatter.1": "msbfs.reseed"}}
+
+
+def test_hlo_stats_name_every_operation_by_its_own_path():
+    cols = [{"id": k} for k in ("rank", "program_id", "hlo_op_name",
+                                "tf_op_name")]
+
+    def row(*v):
+        return {"c": [{"v": x} for x in v]}
+
+    table = {"cols": cols, "rows": [
+        row(1, "7", "fusion.1", "jit(msbfs_block)/while/body/"
+            "vmap(msbfs.nd)/while/body/gather:"),
+        row(2, "7", "fusion.2", ""),
+        row(3, "9", "scatter.1", "jit(_reseed_lanes_impl)/msbfs.reseed/"
+            "scatter:"),
+        row(4, "5", "fusion.3", ".../vmap(msbfs.dd)/or:")]}
+    assert at.stats_scopes(table, ["jit_msbfs_block(7)",
+                                   "jit__reseed_lanes_impl(9)"]) == {
+        "jit_msbfs_block(7)": {"fusion.1": "msbfs.nd", "fusion.2": at.NONE},
         "jit__reseed_lanes_impl(9)": {"scatter.1": "msbfs.reseed"}}
 
 

@@ -24,7 +24,8 @@ def tiny(name: str, seconds: float = 3.0) -> dict:
     return r
 
 
-CELLS = ["graph500.point-zipf", "gap-urand.bfs"]
+KEY_SETS = ["gap-urand.bfs", "graph500-s20.bfs-fixed"]
+CELLS = ["graph500.point-zipf", *KEY_SETS]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -58,7 +59,8 @@ def test_altered_answer_is_not_correct(name, monkeypatch):
     assert res["checks"]["wrong_answers"]["value"] > 0
 
 
-def test_one_lane_altered_in_every_set_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("name", KEY_SETS)
+def test_one_lane_altered_in_every_set_is_not_correct(name, monkeypatch):
     from repro.serve import BFSServeEngine
 
     orig = BFSServeEngine.submit_many
@@ -70,7 +72,7 @@ def test_one_lane_altered_in_every_set_is_not_correct(monkeypatch):
         return res[:-1] + [last]
 
     monkeypatch.setattr(BFSServeEngine, "submit_many", submit_many)
-    res = run.run_cell(tiny("gap-urand.bfs"), require_tpu=False)
+    res = run.run_cell(tiny(name), require_tpu=False)
     assert not res["correct"]
     assert res["checks"]["wrong_answers"]["value"] > 0
 
@@ -94,7 +96,8 @@ def test_half_the_requests_left_out_is_not_correct(monkeypatch):
     assert not res["correct"] and res["failed"] > 0
 
 
-def test_half_the_key_set_left_out_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("name", KEY_SETS)
+def test_half_the_key_set_left_out_is_not_correct(name, monkeypatch):
     from repro.serve import BFSServeEngine
 
     orig = BFSServeEngine.submit_many
@@ -104,6 +107,6 @@ def test_half_the_key_set_left_out_is_not_correct(monkeypatch):
         return orig(self, queries[:half]) + [None] * (len(queries) - half)
 
     monkeypatch.setattr(BFSServeEngine, "submit_many", submit_many)
-    res = run.run_cell(tiny("gap-urand.bfs"), require_tpu=False)
+    res = run.run_cell(tiny(name), require_tpu=False)
     assert not res["correct"]
     assert res["checks"]["unanswered"]["value"] > 0

@@ -57,6 +57,56 @@ def test_popular_vertices_are_the_same_base_vertices_every_seed():
     assert np.array_equal(named[0], named[1])
 
 
+@pytest.mark.parametrize("config", ["graph500", "graph500-s20"])
+def test_fixed_keys_are_the_same_base_vertices_every_seed(config):
+    cfg = dict(run.load_json(f"bench/configs/{config}.json"), scale=10)
+    traffic = run.load_json("bench/traffic/bfs-fixed.json")
+    based, lanes = [], []
+    for s in (SEED, 7):
+        n, src, _ = gen.make_graph(cfg, s)
+        sets = run.key_sets(cfg, traffic, n, src, s)
+        keys = np.concatenate(sets)
+        assert all(k.size == traffic["keys_per_set"] for k in sets)
+        # a Kronecker graph this small has fewer non-isolated vertices
+        # than max_sets whole sets ask for: as many whole sets as there are
+        assert len(sets) == gen.non_isolated(n, src).size // sets[0].size
+        assert np.unique(keys).size == keys.size
+        assert np.isin(keys, gen.non_isolated(n, src)).all()
+        base = np.argsort(gen.labels(n, gen.label_seed(cfg, s)))[keys]
+        lanes.append(base)
+        based.append([np.sort(b) for b in np.split(base, len(sets))])
+    # each set holds the same base vertices for every seed, on other lanes
+    assert all(np.array_equal(a, b) for a, b in zip(*based))
+    assert not np.array_equal(lanes[0], lanes[1])
+
+
+def test_a_label_seed_gives_every_seed_the_same_graph():
+    cfg = dict(run.load_json("bench/configs/graph500-s20.json"), scale=8)
+    assert gen.label_seed(cfg, SEED) == cfg["label_seed"]
+    (n, a, c), (_, b, d) = (gen.make_graph(cfg, s) for s in (SEED, 7))
+    assert np.array_equal(a, b) and np.array_equal(c, d)
+    # the same graph as the seed-labelled configuration's at that seed
+    free = {k: v for k, v in cfg.items() if k != "label_seed"}
+    assert np.array_equal(gen.make_graph(free, cfg["label_seed"])[1], a)
+
+
+def test_seed_drawn_keys_are_unchanged_without_a_base_seed():
+    cfg = dict(run.load_json("bench/configs/gap-urand.json"), scale=10)
+    traffic = run.load_json("bench/traffic/bfs.json")
+    assert "base_seed" not in traffic
+    size = traffic["keys_per_set"]
+    for s in (SEED, 7):
+        n, src, _ = gen.make_graph(cfg, s)
+        cand = gen.non_isolated(n, src)
+        count = size * traffic["max_sets"]
+        want = gen.rng(s, 2).choice(cand, size=min(count, cand.size),
+                                    replace=False)
+        assert np.array_equal(want, gen.search_keys(n, src, count, s))
+        sets = run.key_sets(cfg, traffic, n, src, s)
+        whole = want.size // size * size
+        assert np.array_equal(np.concatenate(sets), want[:whole])
+
+
 def test_rmat_skew_and_urand_uniformity():
     n, src, _ = gen.make_graph(
         dict(run.load_json("bench/configs/graph500.json"), scale=12), 3)
@@ -263,7 +313,8 @@ def test_host_ms_per_boundary_takes_the_union_of_nested_spans():
 
 
 def test_control_fails_the_comparison_on_three_seeds():
-    for name in ("graph500.point-zipf", "gap-urand.bfs"):
+    for name in ("graph500.point-zipf", "gap-urand.bfs",
+                 "graph500-s20.bfs-fixed"):
         c = run.load_cell(name)
         c["config"]["scale"] = 10
         for seed in (SEED, 11, 12):
